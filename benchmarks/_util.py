@@ -17,6 +17,12 @@ def timeit(fn, *, reps: int = 3, warmup: int = 0):
     return float(np.mean(ts)), float(np.std(ts))
 
 
+def frac(x: float | None) -> str:
+    """A roofline fraction for a derived column; ``none`` where the device
+    has no peaks to divide by (``launch/roofline.PEAKS``)."""
+    return "none" if x is None else f"{x:.4f}"
+
+
 def synth_release(n_entries: int, seq_w: int = 64, *, seed: int = 0,
                   base=None, frac_updated: float = 0.0, n_new: int = 0,
                   n_deleted: int = 0):

@@ -5,12 +5,11 @@ Prints ``name,us_per_call,derived`` CSV and merges the same rows into
 the tables it attempted, so a partial or BENCH_TABLES-filtered run no
 longer clobbers earlier results. Set BENCH_N / BENCH_APP_N / BENCH_BATCH_N
 / BENCH_STORE_N / BENCH_SHARD_N / BENCH_SHARDS / BENCH_SERVE_* /
-BENCH_INGEST_* to scale
-(defaults sized
-for a single CPU core; the operations are row-parallel, see DESIGN.md §8
-for the pod-scale throughput argument), and BENCH_TABLES to a
-comma-separated list of table keys (e.g. ``table5,table7``) to run a
-subset.
+BENCH_INGEST_* to scale (defaults sized for a single CPU core), and
+BENCH_TABLES to a comma-separated list of table keys (e.g.
+``table5,table7``) to run a subset. Compiled programs persist in
+``JAX_COMPILATION_CACHE_DIR`` when it is set, else in ``.jax_cache/``
+at the repository root (``kernels/launch.use_compile_cache``).
 """
 from __future__ import annotations
 
@@ -62,6 +61,8 @@ def main() -> None:
     # robust to both `python benchmarks/run.py` and `python -m benchmarks.run`
     sys.path.insert(0, os.path.join(_ROOT, "src"))
     sys.path.insert(0, _ROOT)
+    from repro.kernels.launch import use_compile_cache
+    use_compile_cache(_ROOT)
     from benchmarks import (fig1_growth, roofline_table, table1_lifecycle,
                             table2_incremental, table3_split,
                             table4_application, table5_batched,

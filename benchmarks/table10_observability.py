@@ -7,8 +7,9 @@ Two row families:
     ``shard_route`` via ``route_keys``, ``delta_codec`` via
     ``chain_pack``/``chain_unpack``) and report the per-kernel telemetry
     ``KernelTelemetry`` aggregated: wall us/launch plus the derived
-    roofline fraction and achieved GB/s against the v5e-class constants
-    in ``launch/roofline.py``. A collapsing fraction (or exploding
+    roofline fraction and achieved GB/s against the running chip's peaks
+    in ``launch/roofline.PEAKS`` (``none`` on a device with no entry,
+    the CPU included). A collapsing fraction (or exploding
     us/launch) gates CI via tools/bench_compare.py.
   * ``table10.<primitive>`` — the cost of one observability primitive
     (counter inc, histogram record, span open/close, flight-recorder
@@ -33,7 +34,7 @@ from repro.kernels.shard_route import route_keys
 from repro.obs import FlightRecorder, MetricsRegistry, span
 from repro.obs.kerneltel import KERNELS
 
-from ._util import synth_release, timeit
+from ._util import frac, synth_release, timeit
 
 N = int(os.environ.get("BENCH_OBS_N",
                        os.environ.get("BENCH_BATCH_N", 8_000)))
@@ -129,7 +130,7 @@ def run() -> list[tuple[str, float, str]]:
             continue
         rows.append((
             f"table10.roofline_{kernel}", k["us_per_call"],
-            f"roofline_frac={k['roofline_fraction']:.4f};"
+            f"roofline_frac={frac(k['roofline_fraction'])};"
             f"gbytes_per_s={k['gbytes_per_s']:.2f};"
             f"dominant={k['dominant']};calls={k['calls']}"))
     with open(METRICS_OUT, "w") as f:

@@ -37,7 +37,7 @@ from repro.kernels.delta_codec import chain_pack, chain_unpack, delta_pack
 from repro.kernels.shard_route import key_lanes, route_keys, shard_route
 from repro.obs.kerneltel import KERNELS
 
-from ._util import synth_release, timeit
+from ._util import frac, synth_release, timeit
 
 N = int(os.environ.get("BENCH_KERNEL_N",
                        os.environ.get("BENCH_BATCH_N", 8_000)))
@@ -134,7 +134,7 @@ def _steady_state() -> list[tuple[str, float, str]]:
             continue
         rows.append((
             f"table11.steady_{kernel}", k["us_per_call"],
-            f"roofline_frac={k['roofline_fraction']:.4f};"
+            f"roofline_frac={frac(k['roofline_fraction'])};"
             f"gbytes_per_s={k['gbytes_per_s']:.2f};"
             f"logical_gbytes_per_s={k['logical_gbytes_per_s']:.2f};"
             f"calls={k['calls']}"))

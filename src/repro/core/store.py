@@ -346,9 +346,9 @@ class _CellLog:
         _, tss, ptr = self.csr(n_rows, through_ts=t1)
         if len(tss) == 0:
             return np.zeros(n_rows, np.int32)
-        ts_j = jnp.asarray(tss.astype(np.int32))
-        c1 = np.asarray(kops.masked_cumsum(ts_j, _clamp_ts(t1)))
-        c0 = np.asarray(kops.masked_cumsum(ts_j, _clamp_ts(t0)))
+        c0, c1 = np.asarray(kops.batched_masked_cumsum(
+            jnp.asarray(tss.astype(np.int32)),
+            jnp.asarray([_clamp_ts(t0), _clamp_ts(t1)], np.int32)))
         cum = np.concatenate([[0], c1 - c0])
         return (cum[ptr[1:]] - cum[ptr[:-1]]).astype(np.int32)
 

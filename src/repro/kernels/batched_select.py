@@ -1,16 +1,20 @@
 """Batched Pallas masked-cumsum: one launch materializes MANY versions.
 
-``masked_cumsum`` (version_select.py) answers one query timestamp per
-launch, so materializing N versions of an F-field store costs N*F kernel
-launches, each re-streaming the CSR log. Production platforms re-run
-analyses against many pinned versions concurrently (the paper's §III.C
-workload; OrpheusDB's multi-version checkout), so this kernel computes the
-inclusive cumsum of ``ts <= t_q`` for a *vector* of Q query timestamps in a
-single launch with grid ``(ts_tile, query)``: each grid cell re-reads one
-timestamp tile (already VMEM-resident across the inner query axis) and
-emits the intra-tile cumsum for one query. The tiny per-(query, tile)
-offset cumsum and the CSR boundary gathers run in XLA, exactly as in the
-single-query kernel.
+Materializing version T selects, per row, the newest cell with ts <= T;
+with the cell log in CSR order that is a difference of the running count
+of ``ts <= T`` at segment boundaries (version_select.py has the
+derivation). Production platforms re-run analyses against many pinned
+versions concurrently (the paper's §III.C workload; OrpheusDB's
+multi-version checkout), so one launch computes that running count for a
+*vector* of Q query timestamps over S stacked cell logs: grid
+``(shard, ts_tile)``, each step reading one ts tile once and writing the
+whole ``(Q, tile)`` block of counts. The Q query timestamps ride in SMEM
+(scalar prefetch), and each shard's count carries across its tiles in a
+VMEM scratch, so the kernel writes final counts with no second pass.
+
+Inside a tile the prefix sum is a log-step shift-and-add over the lane
+axis (``pltpu.roll``): integer adds, exact by construction, and a
+primitive the TPU lowering has (it has no ``cumsum``).
 
 The ts tile is no longer hardcoded: ``launch.tile_for("batched_select")``
 resolves it (env override > autotuned winner > default), and callers are
@@ -27,13 +31,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import launch, ref
 from ._compat import interpret_default
-
-#: kept as a module attr for backward compatibility (the pre-autotune
-#: hardcoded tile); live launches resolve through launch.tile_for.
-TILE_C = launch.DEFAULT_TILES["batched_select"]
 
 
 def scan_bucket(n: int) -> int:
@@ -47,12 +48,65 @@ def tile() -> int:
     return launch.tile_for("batched_select")
 
 
-def _batched_masked_cumsum_kernel(ts_ref, tq_ref, cum_ref, tot_ref):
-    t = tq_ref[0]
-    m = (ts_ref[:] <= t).astype(jnp.int32)
-    c = jnp.cumsum(m)
-    cum_ref[0, :] = c
-    tot_ref[0, 0] = c[-1]
+def _lane_cumsum(m, lane):
+    """Inclusive prefix sum of ``m`` along its lane axis: log2(width)
+    steps, each adding the copy rolled ``s`` lanes up, masked below lane
+    ``s`` so nothing wraps around."""
+    s = 1
+    while s < m.shape[1]:
+        m = m + jnp.where(lane >= s, pltpu.roll(m, s, 1), 0)
+        s *= 2
+    return m
+
+
+def _scan_kernel(tq_ref, ts_ref, cum_ref, carry_ref):
+    """One (shard, ts tile) step: for every query q, the count of cells
+    with ts <= tq[q] up to each position of the shard's log. ``carry_ref``
+    holds each query's count through the shard's previous tiles."""
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        carry_ref[...] = jnp.zeros_like(carry_ref)
+
+    ts = ts_ref[...]                                   # (1, tile)
+    lane = jax.lax.broadcasted_iota(jnp.int32, ts.shape, 1)
+    for q in range(cum_ref.shape[0]):
+        c = (_lane_cumsum((ts <= tq_ref[q]).astype(jnp.int32), lane)
+             + carry_ref[q:q + 1, :1])
+        cum_ref[q:q + 1, :] = c
+        carry_ref[q:q + 1, :] = jnp.broadcast_to(c[:, -1:],
+                                                 (1, carry_ref.shape[1]))
+
+
+def _scan(ts_stack, t_queries, *, interpret: bool, tile: int):
+    """(S, C) ts rows, (Q,) queries -> (S, Q, C) inclusive counts of
+    ts <= t_q (traced inside the jitted wrappers below). Pads the cell
+    axis to a tile multiple; the pad value is irrelevant because counts
+    only carry forward and the padded columns are sliced off."""
+    s, c = ts_stack.shape
+    (q,) = t_queries.shape
+    if s == 0 or c == 0 or q == 0:
+        return jnp.zeros((s, q, c), jnp.int32)
+    c_pad = launch.round_up_tile(c, tile)
+    if c_pad != c:
+        pad = jnp.full((s, c_pad - c), jnp.iinfo(ts_stack.dtype).max,
+                       ts_stack.dtype)
+        ts_stack = jnp.concatenate([ts_stack, pad], axis=1)
+    out = pl.pallas_call(
+        _scan_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(s, c_pad // tile),
+            in_specs=[pl.BlockSpec((None, 1, tile),
+                                   lambda k, i, tq: (k, 0, i))],
+            out_specs=pl.BlockSpec((None, q, tile),
+                                   lambda k, i, tq: (k, 0, i)),
+            scratch_shapes=[pltpu.VMEM((q, 128), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((s, q, c_pad), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(t_queries.astype(jnp.int32), ts_stack[:, None, :])
+    return out[:, :, :c]
 
 
 def batched_masked_cumsum(ts: jax.Array, t_queries: jax.Array, *,
@@ -75,49 +129,7 @@ def _batched_masked_cumsum(ts, t_queries, *, interpret, tile):
         if interpret_default():
             return ref.ref_batched_masked_cumsum(ts, t_queries)
         interpret = False
-    (c,) = ts.shape
-    (q,) = t_queries.shape
-    if c == 0 or q == 0:
-        return jnp.zeros((q, c), jnp.int32)
-    c_pad = launch.round_up_tile(c, tile)
-    if c_pad != c:
-        # pad above every possible query (queries are clamped below TS_MAX)
-        pad = jnp.full((c_pad - c,), jnp.iinfo(ts.dtype).max, ts.dtype)
-        ts = jnp.concatenate([ts, pad])
-    n_tiles = c_pad // tile
-    intra, totals = pl.pallas_call(
-        _batched_masked_cumsum_kernel,
-        grid=(n_tiles, q),
-        in_specs=[
-            pl.BlockSpec((tile,), lambda i, j: (i,)),
-            pl.BlockSpec((1,), lambda i, j: (j,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile), lambda i, j: (j, i)),
-            pl.BlockSpec((1, 1), lambda i, j: (j, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((q, c_pad), jnp.int32),
-            jax.ShapeDtypeStruct((q, n_tiles), jnp.int32),
-        ],
-        interpret=interpret,
-    )(ts, t_queries)
-    offsets = jnp.concatenate(
-        [jnp.zeros((q, 1), jnp.int32), jnp.cumsum(totals, axis=1)[:, :-1]],
-        axis=1)
-    # broadcast-reshape, not jnp.repeat: adds the per-tile offset without
-    # materializing a (q, c_pad) repeat buffer first
-    out = (intra.reshape(q, n_tiles, tile)
-           + offsets[:, :, None]).reshape(q, c_pad)
-    return out[:, :c]
-
-
-def _stacked_masked_cumsum_kernel(ts_ref, tq_ref, cum_ref, tot_ref):
-    t = tq_ref[0]
-    m = (ts_ref[0, :] <= t).astype(jnp.int32)
-    c = jnp.cumsum(m)
-    cum_ref[0, 0, :] = c
-    tot_ref[0, 0, 0] = c[-1]
+    return _scan(ts[None], t_queries, interpret=interpret, tile=tile)[0]
 
 
 def stacked_masked_cumsum(ts_stack: jax.Array, t_queries: jax.Array, *,
@@ -141,39 +153,7 @@ def _stacked_masked_cumsum(ts_stack, t_queries, *, interpret, tile):
         if interpret_default():
             return ref.ref_stacked_masked_cumsum(ts_stack, t_queries)
         interpret = False
-    s, c = ts_stack.shape
-    (q,) = t_queries.shape
-    if s == 0 or c == 0 or q == 0:
-        return jnp.zeros((s, q, c), jnp.int32)
-    c_pad = launch.round_up_tile(c, tile)
-    if c_pad != c:
-        pad = jnp.full((s, c_pad - c), jnp.iinfo(ts_stack.dtype).max,
-                       ts_stack.dtype)
-        ts_stack = jnp.concatenate([ts_stack, pad], axis=1)
-    n_tiles = c_pad // tile
-    intra, totals = pl.pallas_call(
-        _stacked_masked_cumsum_kernel,
-        grid=(s, n_tiles, q),
-        in_specs=[
-            pl.BlockSpec((1, tile), lambda k, i, j: (k, i)),
-            pl.BlockSpec((1,), lambda k, i, j: (j,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, tile), lambda k, i, j: (k, j, i)),
-            pl.BlockSpec((1, 1, 1), lambda k, i, j: (k, j, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((s, q, c_pad), jnp.int32),
-            jax.ShapeDtypeStruct((s, q, n_tiles), jnp.int32),
-        ],
-        interpret=interpret,
-    )(ts_stack, t_queries)
-    offsets = jnp.concatenate(
-        [jnp.zeros((s, q, 1), jnp.int32),
-         jnp.cumsum(totals, axis=2)[:, :, :-1]], axis=2)
-    out = (intra.reshape(s, q, n_tiles, tile)
-           + offsets[:, :, :, None]).reshape(s, q, c_pad)
-    return out[:, :, :c]
+    return _scan(ts_stack, t_queries, interpret=interpret, tile=tile)
 
 
 def scan_cache_size() -> int:
@@ -227,17 +207,18 @@ def stacked_boundary_select(ts_stack, t_queries, boundaries, *, mesh=None,
 def _mesh_boundary_select(mesh, interpret: bool | None):
     """Compiled shard_map'd boundary select for one mesh, cached so the
     serving hot path never retraces (jit keyed per operand shape)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(ts, qs, bnd):
         cum = stacked_masked_cumsum(ts, qs, interpret=interpret)
         return _boundary_take(cum, bnd)
 
-    return jax.jit(shard_map(
+    # check_vma=False: the Pallas scan's out_shape carries no varying-axes
+    # annotation; every shard's output is its own (in/out specs say so)
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("shard", None), P(), P("shard", None)),
-        out_specs=P("shard", None, None)))
+        out_specs=P("shard", None, None), check_vma=False))
 
 
 def batched_version_select(log_vals, log_ts, row_ptr, t_queries, *,

@@ -7,9 +7,8 @@ keep-mask over the cell timestamps and the (C, W) value-byte rewrite into
 the new CSR order — now run on device through the shared launch helper
 (kernels/launch.py), under the ``compact_rewrite`` telemetry name:
 
-  * a row-tiled Pallas kernel computes the ``ts > horizon`` keep mask and
-    per-tile survivor counts (bandwidth-bound, same launch family as
-    shard_route);
+  * a row-tiled Pallas kernel computes the ``ts > horizon`` keep mask
+    (bandwidth-bound, same launch family as shard_route);
   * ONE fused device gather permutes base + surviving cell values into
     the final lexsorted order (the host only handles the small int32
     index vectors: chain heads, lexsort keys, CSR pointer rebuild).
@@ -32,18 +31,16 @@ from . import launch
 from ._compat import interpret_default
 
 
-def _keep_mask_kernel(ts_ref, keep_ref, cnt_ref, *, cutoff: int):
-    k = (ts_ref[:] > cutoff).astype(jnp.int32)
-    keep_ref[:] = k
-    cnt_ref[0] = jnp.sum(k)
+def _keep_mask_kernel(ts_ref, keep_ref, *, cutoff: int):
+    keep_ref[:] = (ts_ref[:] > cutoff).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("cutoff", "interpret", "tile"))
 def _keep_mask(ts32, *, cutoff, interpret, tile):
-    return launch.tiled_rows(
+    (keep,) = launch.tiled_rows(
         functools.partial(_keep_mask_kernel, cutoff=cutoff),
-        [ts32], [((), jnp.int32, "rows"), ((), jnp.int32, "tile")],
-        tile=tile, interpret=interpret)
+        [ts32], [((), jnp.int32)], tile=tile, interpret=interpret)
+    return keep
 
 
 def ref_compact_rewrite(vals, tss, ptr, base_vals, base_found, before_ts,
@@ -107,7 +104,7 @@ def _device_rewrite(vals, tss, ptr, base_vals, base_found, before_ts,
     # stored device timestamps are int32 by convention (core/store.py
     # clamps queries below TS_MAX), so the mask kernel compares in int32
     cutoff = int(min(max(int(before_ts), -(2**31) + 1), 2**31 - 2))
-    keep_dev, _cnts = _keep_mask(jnp.asarray(tss.astype(np.int32)),
+    keep_dev = _keep_mask(jnp.asarray(tss.astype(np.int32)),
                                  cutoff=cutoff, interpret=interpret,
                                  tile=tile)
     keep = np.asarray(keep_dev).astype(bool)

@@ -6,8 +6,12 @@ updated row, the delta against the row's previous value: arithmetic
 difference for integer fields and bitwise XOR for float fields (unchanged
 exponent/mantissa bytes zero out, which downstream byte-level entropy coding
 exploits). Both directions are single-pass streaming VPU kernels; pack
-additionally emits the per-tile max |delta| so the host can narrow int32
-deltas to int16/int8 segments.
+also returns the max |delta| (the nonzero count for XOR lanes), reduced
+in XLA over the kernel's output, so the host can narrow int32 deltas to
+int16/int8 segments. Integer lanes are widened to int32 inside the
+kernels: the TPU's vector unit has no 8- or 16-bit integer add or
+subtract, and truncating the int32 result back is exactly the stored
+dtype's wraparound.
 
 On-disk chain format (used by ``core/segments.py`` segment files): cells
 are sorted by (row, ts); within each row's run ("chain") the first cell is
@@ -44,46 +48,32 @@ import numpy as np
 from . import launch, ref
 from ._compat import interpret_default
 
-#: pre-autotune hardcoded tile, kept for backward compatibility; live
-#: launches resolve through launch.tile_for("delta_codec").
-TILE_N = launch.DEFAULT_TILES["delta_codec"]
-
 # sign-bit flip constant for unsigned int32 compares; kept a Python int so
 # Pallas kernels don't capture a traced array constant
 _I32_SIGN = -(2**31)
 
 
-def _pack_int_kernel(new_ref, old_ref, delta_ref, maxabs_ref):
-    d = new_ref[:, :] - old_ref[:, :]
-    delta_ref[:, :] = d
-    # widen before |.|: the stat ref is int32 and abs(int8 -128) overflows
-    maxabs_ref[0] = jnp.max(jnp.abs(d.astype(jnp.int32)))
+def _pack_int_kernel(new_ref, old_ref, delta_ref):
+    d = new_ref[...].astype(jnp.int32) - old_ref[...].astype(jnp.int32)
+    delta_ref[...] = d.astype(delta_ref.dtype)
 
 
-def _pack_xor_kernel(new_ref, old_ref, delta_ref, nz_ref):
-    d = new_ref[:, :] ^ old_ref[:, :]
-    delta_ref[:, :] = d
-    nz_ref[0] = jnp.sum((d != 0).astype(jnp.int32))
+def _unpack_int_kernel(delta_ref, old_ref, new_ref):
+    v = delta_ref[...].astype(jnp.int32) + old_ref[...].astype(jnp.int32)
+    new_ref[...] = v.astype(new_ref.dtype)
 
 
-def _unpack_int_kernel(delta_ref, old_ref, new_ref, stat_ref):
-    new_ref[:, :] = delta_ref[:, :] + old_ref[:, :]
-    stat_ref[0] = 0
+def _xor_kernel(a_ref, b_ref, out_ref):
+    """Float lanes: XOR packs and unpacks alike (it is its own inverse)."""
+    out_ref[...] = a_ref[...] ^ b_ref[...]
 
 
-def _unpack_xor_kernel(delta_ref, old_ref, new_ref, stat_ref):
-    new_ref[:, :] = delta_ref[:, :] ^ old_ref[:, :]
-    stat_ref[0] = 0
-
-
-def _run_2d(kernel, a, b, out_dtypes, *, interpret, tile):
+def _run_2d(kernel, a, b, *, interpret, tile):
     """The codec family's launch shape, via the shared helper: two (N, W)
-    inputs, an (N, W) output and a per-tile stat."""
-    w = a.shape[1]
-    return launch.tiled_rows(
-        kernel, [a, b],
-        [((w,), out_dtypes[0], "rows"), ((), out_dtypes[1], "tile")],
-        tile=tile, interpret=interpret)
+    inputs and an (N, W) output of the same lane dtype."""
+    (out,) = launch.tiled_rows(kernel, [a, b], [(a.shape[1:], a.dtype)],
+                               tile=tile, interpret=interpret)
+    return out
 
 
 def _as_int_lanes(x: jax.Array) -> tuple[jax.Array, jnp.dtype]:
@@ -96,7 +86,7 @@ def _as_int_lanes(x: jax.Array) -> tuple[jax.Array, jnp.dtype]:
 def delta_pack(new: jax.Array, old: jax.Array, *,
                interpret: bool | None = None, tile: int | None = None):
     """Pack (new, old) -> (delta, stat). Floats: XOR lanes + nonzero count;
-    ints: arithmetic delta + per-tile max|delta| (for narrowing).
+    ints: arithmetic delta + max|delta| (for narrowing); ``stat`` is (1,).
     interpret=None: kernel on TPU, jitted ref on CPU; True: force kernel."""
     if tile is None:
         tile = launch.tile_for("delta_codec", n=new.shape[0])
@@ -105,23 +95,20 @@ def delta_pack(new: jax.Array, old: jax.Array, *,
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile"))
 def _delta_pack(new, old, *, interpret, tile):
-    if interpret is None:
-        if interpret_default():
-            d = ref.ref_delta_pack(new, old)
-            di, _ = _as_int_lanes(d)
-            stat = (jnp.sum((di != 0).astype(jnp.int32))[None]
-                    if jnp.issubdtype(new.dtype, jnp.floating)
-                    else jnp.max(jnp.abs(di.astype(jnp.int32)))[None])
-            return d, stat
-        interpret = False
     is_float = jnp.issubdtype(new.dtype, jnp.floating)
-    a, ib = _as_int_lanes(new)
-    b, _ = _as_int_lanes(old)
-    kernel = _pack_xor_kernel if is_float else _pack_int_kernel
-    delta, stat = _run_2d(kernel, a, b, (ib, jnp.int32), interpret=interpret,
-                          tile=tile)
-    if is_float:
-        delta = delta.view(new.dtype)
+    if interpret is None and interpret_default():
+        delta = ref.ref_delta_pack(new, old)
+    else:
+        a, _ = _as_int_lanes(new)
+        b, _ = _as_int_lanes(old)
+        kernel = _xor_kernel if is_float else _pack_int_kernel
+        delta = _run_2d(kernel, a, b, interpret=bool(interpret), tile=tile)
+        if is_float:
+            delta = delta.view(new.dtype)
+    di, _ = _as_int_lanes(delta)
+    # widen before |.|: abs(int8 -128) overflows in the stored dtype
+    stat = (jnp.sum((di != 0).astype(jnp.int32))[None] if is_float
+            else jnp.max(jnp.abs(di.astype(jnp.int32)))[None])
     return delta, stat
 
 
@@ -139,11 +126,10 @@ def _delta_unpack(delta, old, *, interpret, tile):
             return ref.ref_delta_unpack(delta, old)
         interpret = False
     is_float = jnp.issubdtype(delta.dtype, jnp.floating)
-    a, ib = _as_int_lanes(delta)
+    a, _ = _as_int_lanes(delta)
     b, _ = _as_int_lanes(old)
-    kernel = _unpack_xor_kernel if is_float else _unpack_int_kernel
-    new, _ = _run_2d(kernel, a, b, (ib, jnp.int32), interpret=interpret,
-                     tile=tile)
+    kernel = _xor_kernel if is_float else _unpack_int_kernel
+    new = _run_2d(kernel, a, b, interpret=interpret, tile=tile)
     if is_float:
         new = new.view(delta.dtype)
     return new
@@ -152,7 +138,7 @@ def _delta_unpack(delta, old, *, interpret, tile):
 # -- two-lane 8-byte device path ----------------------------------------------
 
 def _pack_wide_kernel(alo_ref, ahi_ref, blo_ref, bhi_ref,
-                      dlo_ref, dhi_ref, stat_ref):
+                      dlo_ref, dhi_ref):
     """64-bit modular subtract on (lo, hi) int32 lanes: lo borrows into hi
     when unsigned a_lo < b_lo (sign-flip trick — int32 has no uint compare)."""
     alo, ahi = alo_ref[:, :], ahi_ref[:, :]
@@ -160,11 +146,10 @@ def _pack_wide_kernel(alo_ref, ahi_ref, blo_ref, bhi_ref,
     borrow = ((alo ^ _I32_SIGN) < (blo ^ _I32_SIGN)).astype(jnp.int32)
     dlo_ref[:, :] = alo - blo
     dhi_ref[:, :] = ahi - bhi - borrow
-    stat_ref[0] = 0
 
 
 def _unpack_wide_kernel(dlo_ref, dhi_ref, olo_ref, ohi_ref,
-                        nlo_ref, nhi_ref, stat_ref):
+                        nlo_ref, nhi_ref):
     """64-bit modular add on (lo, hi) lanes: the lo sum wrapped (unsigned
     sum < either addend) iff a carry must propagate into hi."""
     dlo, dhi = dlo_ref[:, :], dhi_ref[:, :]
@@ -173,15 +158,13 @@ def _unpack_wide_kernel(dlo_ref, dhi_ref, olo_ref, ohi_ref,
     carry = ((lo ^ _I32_SIGN) < (dlo ^ _I32_SIGN)).astype(jnp.int32)
     nlo_ref[:, :] = lo
     nhi_ref[:, :] = dhi + ohi + carry
-    stat_ref[0] = 0
 
 
 def _xor_wide_kernel(alo_ref, ahi_ref, blo_ref, bhi_ref,
-                     dlo_ref, dhi_ref, stat_ref):
+                     dlo_ref, dhi_ref):
     """float64 XOR decomposes lane-wise — same kernel packs and unpacks."""
     dlo_ref[:, :] = alo_ref[:, :] ^ blo_ref[:, :]
     dhi_ref[:, :] = ahi_ref[:, :] ^ bhi_ref[:, :]
-    stat_ref[0] = 0
 
 
 def split_lanes64(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -211,10 +194,9 @@ def _wide_2lane(alo, ahi, blo, bhi, *, op, interpret, tile):
     kernel = {"sub": _pack_wide_kernel, "add": _unpack_wide_kernel,
               "xor": _xor_wide_kernel}[op]
     w = alo.shape[1]
-    lo, hi, _ = launch.tiled_rows(
+    lo, hi = launch.tiled_rows(
         kernel, [alo, ahi, blo, bhi],
-        [((w,), jnp.int32, "rows"), ((w,), jnp.int32, "rows"),
-         ((), jnp.int32, "tile")],
+        [((w,), jnp.int32), ((w,), jnp.int32)],
         tile=tile, interpret=interpret)
     return lo, hi
 
@@ -258,6 +240,7 @@ def delta_unpack_wide(delta: np.ndarray, old: np.ndarray, *,
 
 # -- device-side chain decode (segmented scan) --------------------------------
 
+@functools.partial(jax.jit, static_argnames=("xor",))
 def chain_decode(deltas: jax.Array, heads: jax.Array, *,
                  xor: bool = False) -> jax.Array:
     """Decode chain deltas ON DEVICE: deltas (C, W) int lanes where the
@@ -269,6 +252,8 @@ def chain_decode(deltas: jax.Array, heads: jax.Array, *,
 
     This is what lets the fused superlog keep fields delta-packed in HBM
     and decode inside the gather path instead of uploading decoded cells.
+    Jitted: run eagerly, the scan's log-depth ladder of slices and adds
+    would compile one small program per op and per operand shape.
     """
     h = jnp.asarray(heads, bool).reshape(-1, 1)
     if xor:

@@ -8,9 +8,11 @@ wrap the host-sync site in ``kerneltel``. This module is that plumbing,
 written once:
 
   * **Tile resolution** (:func:`tile_for`): ``GESTORE_TILE_<KERNEL>`` env
-    override > autotuned winner from the on-disk cache > built-in default
-    (the old hardcoded ``TILE_C``/``TILE_N`` values). Resolution is pure
-    host Python and happens *outside* jit, so the tile is a static launch
+    override > autotuned winner from the on-disk cache > built-in default.
+    Every tile must be one the TPU compiler accepts for that kernel's
+    blocks (:data:`TILE_QUANTUM`); an override that is not is rejected
+    here instead of failing deep inside Mosaic. Resolution is pure host
+    Python and happens *outside* jit, so the tile is a static launch
     parameter.
   * **Power-of-two shape buckets** (:func:`pow2_bucket`): the retrace
     killer. Operand leading dims are padded up to the next power of two so
@@ -21,14 +23,15 @@ written once:
   * **Autotune sweep** (:func:`sweep`): explicit, never implicit. The
     serving path only ever *reads* the cache; the sweep runs when
     ``benchmarks/table11_kernels.py`` (or a caller) asks for it, and the
-    winning tile per ``(kernel, platform, shape bucket)`` is persisted to
-    ``GESTORE_TILE_CACHE`` (default ``~/.cache/gestore/tiles.json``) so it
-    runs once per machine. CI uploads the file as an artifact and restores
-    it with ``actions/cache`` so repeat runs skip the sweep entirely.
+    winning tile per ``(kernel, platform, shape bucket)`` is kept in memory
+    and, when ``GESTORE_TILE_CACHE`` names a file, persisted there so it
+    runs once per machine. There is no default file: without the variable
+    every launch uses the built-in tiles, which live in this (committed)
+    module. CI points the variable at a cached artifact so repeat runs
+    skip the sweep entirely.
   * **Row-tiled pallas_call builder** (:func:`tiled_rows`): the shared
     1-D-grid launch shape (pad rows to a tile multiple, per-tile row
-    blocks plus optional per-tile stat outputs, slice back to the logical
-    row count).
+    blocks, slice back to the logical row count).
   * **Telemetry** (:func:`measured`): the ``kerneltel.launch`` wrap used
     by every host-facing call site, carrying *both* the logical traffic
     model and the padded bytes that actually move (bucket slack must not
@@ -50,21 +53,33 @@ from jax.experimental import pallas as pl
 
 from ._compat import cdiv
 
-#: built-in tiles — exactly the values the kernels hardcoded before the
-#: launch helper existed, so behavior without env/cache input is unchanged.
+#: built-in tiles: the launch tile of each kernel when neither an env
+#: override nor a cached sweep winner applies.
 DEFAULT_TILES = {
     "batched_select": 2048,
-    "shard_route": 512,
+    "shard_route": 1024,
     "delta_codec": 512,
-    "compact_rewrite": 512,
+    "compact_rewrite": 1024,
+}
+
+#: every tile of a kernel must be a multiple of its quantum, the block
+#: granule the TPU compiler accepts: the scan's tile is the lane axis of
+#: its blocks (128 lanes); shard_route and compact_rewrite stream 1-D
+#: int32 rows, which XLA lays out in 1024-element tiles; the codec's row
+#: blocks hold int8 lanes, packed 32 rows to a sublane tile.
+TILE_QUANTUM = {
+    "batched_select": 128,
+    "shard_route": 1024,
+    "delta_codec": 32,
+    "compact_rewrite": 1024,
 }
 
 #: default sweep candidates per kernel (table11 can widen via env).
 SWEEP_CANDIDATES = {
     "batched_select": (512, 1024, 2048, 4096),
-    "shard_route": (256, 512, 1024, 2048),
+    "shard_route": (1024, 2048, 4096, 8192),
     "delta_codec": (256, 512, 1024, 2048),
-    "compact_rewrite": (256, 512, 1024, 2048),
+    "compact_rewrite": (1024, 2048, 4096, 8192),
 }
 
 ENV_PREFIX = "GESTORE_TILE_"
@@ -91,13 +106,16 @@ def round_up_tile(n: int, tile: int) -> int:
 
 # -- tile resolution ----------------------------------------------------------
 
-def cache_path() -> str:
-    """Location of the on-disk autotune winner cache."""
-    p = os.environ.get(CACHE_ENV, "").strip()
-    if p:
-        return p
-    return os.path.join(os.path.expanduser("~"), ".cache", "gestore",
-                        "tiles.json")
+def cache_path() -> str | None:
+    """The on-disk autotune winner cache named by ``GESTORE_TILE_CACHE``,
+    or None: winners then live in memory only and nothing outside the
+    repository changes which tiles a launch compiles with."""
+    return os.environ.get(CACHE_ENV, "").strip() or None
+
+
+def tile_ok(kernel: str, tile: int) -> bool:
+    """Whether the TPU compiler accepts ``tile`` for ``kernel``'s blocks."""
+    return tile > 0 and tile % TILE_QUANTUM.get(kernel, 8) == 0
 
 
 def _cache_key(kernel: str, bucket: int, platform: str | None = None) -> str:
@@ -110,8 +128,11 @@ def _load_winners() -> dict[str, int]:
     with _lock:
         if _winners is None:
             _winners = {}
+            path = cache_path()
             try:
-                with open(cache_path()) as f:
+                if path is None:
+                    raise FileNotFoundError
+                with open(path) as f:
                     raw = json.load(f)
                 _winners = {str(k): int(v) for k, v in raw.items()
                             if isinstance(v, (int, float))}
@@ -129,13 +150,16 @@ def reset_cache() -> None:
 
 def record_winner(kernel: str, bucket: int, tile: int,
                   platform: str | None = None) -> None:
-    """Persist an autotuned winner to memory + the on-disk cache (best
-    effort: an unwritable cache dir degrades to in-memory only)."""
+    """Record an autotuned winner in memory and, when ``GESTORE_TILE_CACHE``
+    is set, in that file (best effort: an unwritable cache dir degrades to
+    in-memory only)."""
     winners = _load_winners()
     with _lock:
         winners[_cache_key(kernel, bucket, platform)] = int(tile)
         payload = dict(winners)
     path = cache_path()
+    if path is None:
+        return
     try:
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         tmp = path + ".tmp"
@@ -152,18 +176,27 @@ def tile_for(kernel: str, n: int | None = None) -> int:
     Precedence: ``GESTORE_TILE_<KERNEL>`` env var > autotuned winner for
     this (kernel, platform, pow2 bucket of n) > ``DEFAULT_TILES``. Always
     a plain positive int — callers pass it to jit as a static arg.
+
+    Raises:
+      ValueError: the env override is not an integer the chip accepts
+        for this kernel (see :data:`TILE_QUANTUM`). A cached winner that
+        the chip would refuse is ignored instead.
     """
-    env = os.environ.get(ENV_PREFIX + kernel.upper(), "").strip()
+    name = ENV_PREFIX + kernel.upper()
+    env = os.environ.get(name, "").strip()
     if env:
         try:
             t = int(env)
-            if t > 0:
-                return t
         except ValueError:
-            pass  # malformed override: fall through to cache/default
+            t = 0
+        if not tile_ok(kernel, t):
+            raise ValueError(
+                f"{name}={env!r}: the {kernel} tile must be a positive "
+                f"multiple of {TILE_QUANTUM.get(kernel, 8)}")
+        return t
     if n is not None:
         w = _load_winners().get(_cache_key(kernel, pow2_bucket(n)))
-        if w:
+        if w and tile_ok(kernel, w):
             return w
     return DEFAULT_TILES.get(kernel, 512)
 
@@ -190,6 +223,10 @@ def sweep(kernel: str, bench, *, n: int, candidates=None,
             return {"tile": w, "bucket": bucket, "cached": True, "walls": {}}
     cands = tuple(candidates or SWEEP_CANDIDATES.get(
         kernel, (256, 512, 1024, 2048)))
+    bad = [t for t in cands if not tile_ok(kernel, int(t))]
+    if bad:
+        raise ValueError(f"{kernel}: the chip refuses tiles {bad} (not "
+                         f"multiples of {TILE_QUANTUM.get(kernel, 8)})")
     walls = {int(t): float(bench(int(t))) for t in cands}
     best = min(walls, key=walls.get)
     record_winner(kernel, bucket, best)
@@ -217,10 +254,10 @@ def tiled_rows(body, inputs, outs, *, tile: int, interpret: bool):
         axis 0 to a ``tile`` multiple (callers that need a non-zero pad
         value pad before calling, as batched_select does with its
         above-every-query sentinel).
-      outs: list of ``(trailing_shape, dtype, kind)``; kind ``"rows"`` is a
-        per-row output (block ``(tile, *trailing)``, sliced back to N) and
-        ``"tile"`` a per-tile stat (block ``(1, *trailing)``, returned at
-        full ``n_tiles`` length).
+      outs: list of ``(trailing_shape, dtype)`` per-row outputs (block
+        ``(tile, *trailing)``, sliced back to N). Per-tile statistics are
+        not kernel outputs: a ``(1,)`` block breaks the TPU's (8, 128)
+        block rule, so callers reduce the row outputs in XLA instead.
       tile: static tile size from :func:`tile_for`.
       interpret: pallas interpret flag (resolved by the caller's dispatch).
 
@@ -231,22 +268,16 @@ def tiled_rows(body, inputs, outs, *, tile: int, interpret: bool):
     if n_pad != n:
         inputs = [jnp.pad(a, ((0, n_pad - n),) + ((0, 0),) * (a.ndim - 1))
                   for a in inputs]
-    n_tiles = n_pad // tile
     in_specs = [pl.BlockSpec((tile,) + a.shape[1:], _row_map(a.ndim))
                 for a in inputs]
-    out_specs, out_shape = [], []
-    for trailing, dtype, kind in outs:
-        trailing = tuple(trailing)
-        lead = tile if kind == "rows" else 1
-        rows = n_pad if kind == "rows" else n_tiles
-        out_specs.append(pl.BlockSpec((lead,) + trailing,
-                                      _row_map(1 + len(trailing))))
-        out_shape.append(jax.ShapeDtypeStruct((rows,) + trailing, dtype))
-    res = pl.pallas_call(body, grid=(n_tiles,), in_specs=in_specs,
+    out_specs = [pl.BlockSpec((tile,) + tuple(t), _row_map(1 + len(t)))
+                 for t, _d in outs]
+    out_shape = [jax.ShapeDtypeStruct((n_pad,) + tuple(t), d)
+                 for t, d in outs]
+    res = pl.pallas_call(body, grid=(n_pad // tile,), in_specs=in_specs,
                          out_specs=out_specs, out_shape=out_shape,
                          interpret=interpret)(*inputs)
-    return tuple(r[:n] if k == "rows" else r
-                 for r, (_t, _d, k) in zip(res, outs))
+    return tuple(r[:n] for r in res)
 
 
 # -- telemetry ----------------------------------------------------------------
@@ -258,3 +289,26 @@ def measured(kernel: str, *, nbytes: float, flops: float,
     from repro.obs import kerneltel
     return kerneltel.launch(kernel, nbytes=nbytes, flops=flops,
                             padded_nbytes=padded_nbytes)
+
+
+# -- persistent compile cache -------------------------------------------------
+
+#: the compile cache's directory under the checkout root when
+#: ``JAX_COMPILATION_CACHE_DIR`` does not name one
+COMPILE_CACHE_DIR = ".jax_cache"
+
+
+def use_compile_cache(root: str) -> str:
+    """Turn on JAX's persistent compile cache for an entry point; returns
+    its directory. ``JAX_COMPILATION_CACHE_DIR``, when set, is used as it
+    is: JAX reads it itself and nothing else is set here. Otherwise the
+    cache goes to the fixed ``<root>/.jax_cache`` — the path is part of
+    every cache key, so a directory that moved from run to run would
+    never hit. Entry points (``chip_smoke.py``, ``benchmarks/run.py``)
+    call this; importing the library never does."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if env:
+        return env
+    path = os.path.join(os.path.abspath(root), COMPILE_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

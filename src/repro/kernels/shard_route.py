@@ -28,10 +28,6 @@ import jax.numpy as jnp
 from . import launch, ref
 from ._compat import interpret_default
 
-#: pre-autotune hardcoded tile, kept for backward compatibility; live
-#: launches resolve through launch.tile_for("shard_route").
-TILE_N = launch.DEFAULT_TILES["shard_route"]
-
 #: routing-function version tag, persisted in shard manifests: a store
 #: written under one tag must never be extended by a different hash.
 ROUTING_VERSION = "xor-rotate-fold-v1"
@@ -76,7 +72,7 @@ def _shard_route(lanes, lengths, n_shards, *, interpret, tile):
         return jnp.zeros((0,), jnp.int32)
     (out,) = launch.tiled_rows(
         functools.partial(_shard_route_kernel, w=w, n_shards=n_shards),
-        [lanes, lengths], [((), jnp.int32, "rows")],
+        [lanes, lengths], [((), jnp.int32)],
         tile=tile, interpret=interpret)
     return out
 

@@ -1,78 +1,28 @@
-"""Pallas masked-cumsum kernel: the scan behind get_version / get_increment.
+"""Single-query version selection: the cold path behind get_version /
+get_increment.
 
 GeStore materializes version T by selecting, for every row's cell chain, the
 newest cell with ts <= T (paper §III.C). With the cell log in CSR order
 (sorted by (row, ts)), timestamps are ascending inside each row segment, so
 the per-row answer index is ``row_ptr[i] + count(ts_segment <= T) - 1`` and
 the count is a difference of the GLOBAL inclusive cumsum of the 0/1 mask
-(ts <= T) at segment boundaries.
-
-The kernel computes that cumsum hierarchically: pass 1 (this kernel) emits
-per-tile intra-cumsum plus per-tile totals; the (tiny) tile-offset cumsum and
-the boundary gathers run in XLA. This keeps the hot O(C) pass in a single
-streaming Pallas kernel with bounded VMEM, with no reliance on cross-grid
-scratch carry semantics.
+(ts <= T) at segment boundaries. That cumsum is the batched scan kernel
+(batched_select.py) asked one query.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from . import ref
-from ._compat import cdiv, interpret_default
-
-TILE_C = 2048
+from .batched_select import batched_masked_cumsum
 
 
-def _masked_cumsum_kernel(ts_ref, t_ref, cum_ref, tot_ref):
-    t = t_ref[0]
-    m = (ts_ref[:] <= t).astype(jnp.int32)
-    c = jnp.cumsum(m)
-    cum_ref[:] = c
-    tot_ref[0] = c[-1]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def masked_cumsum(ts: jax.Array, t_query, *, interpret: bool | None = None) -> jax.Array:
+def masked_cumsum(ts: jax.Array, t_query, *,
+                  interpret: bool | None = None) -> jax.Array:
     """ts: (C,) -> (C,) int32 inclusive cumsum of (ts <= t_query).
     interpret=None: kernel on TPU, jitted ref on CPU; True: force kernel."""
-    if interpret is None:
-        if interpret_default():
-            return ref.ref_masked_cumsum(ts, jnp.asarray(t_query, ts.dtype))
-        interpret = False
-    (c,) = ts.shape
-    if c == 0:
-        return jnp.zeros((0,), jnp.int32)
-    c_pad = cdiv(c, TILE_C) * TILE_C
-    tq = jnp.asarray(t_query, dtype=ts.dtype)
-    if c_pad != c:
-        # pad with a value > t_query so the padding never counts
-        ts = jnp.concatenate(
-            [ts, jnp.full((c_pad - c,), tq + jnp.asarray(1, ts.dtype), ts.dtype)])
-    n_tiles = c_pad // TILE_C
-    intra, totals = pl.pallas_call(
-        _masked_cumsum_kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((TILE_C,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((TILE_C,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((c_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(ts, tq[None])
-    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(totals)[:-1]])
-    out = intra + jnp.repeat(offsets, TILE_C, total_repeat_length=c_pad)
-    return out[:c]
+    tq = jnp.asarray(t_query, ts.dtype).reshape(1)
+    return batched_masked_cumsum(ts, tq, interpret=interpret)[0]
 
 
 def version_select(log_vals, log_ts, row_ptr, t_query, *, interpret: bool | None = None):

@@ -14,10 +14,21 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    """A mesh whose axes GSPMD shards automatically. The model code places
+    parameters by NamedSharding and activations by sharding constraints
+    and lets the partitioner propagate the rest; ``jax.make_mesh``'s
+    default of explicit axes would instead demand an output sharding for
+    every ambiguous op (e.g. the gather from the vocab-sharded embedding
+    table)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_shard_mesh(n_shards: int, devices=None):
@@ -36,7 +47,7 @@ def make_test_mesh(devices: int | None = None):
     """Small mesh for CPU distributed tests (8 host devices -> (2, 4))."""
     n = devices or len(jax.devices())
     if n >= 8:
-        return jax.make_mesh((n // 4, 4), ("data", "model"))
+        return _auto_mesh((n // 4, 4), ("data", "model"))
     if n >= 2:
-        return jax.make_mesh((n // 2, 2), ("data", "model"))
-    return jax.make_mesh((1, 1), ("data", "model"))
+        return _auto_mesh((n // 2, 2), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))

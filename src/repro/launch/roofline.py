@@ -9,16 +9,33 @@ cost_analysis() of the SPMD-partitioned executable reports per-device
 FLOPs/bytes; collective bytes are parsed from the partitioned HLO text with
 ring-algorithm traffic factors (all-reduce 2(n-1)/n, all-gather/all-to-all
 (n-1)/n on the gathered size, reduce-scatter (n-1) on the scattered size,
-permute 1x). Hardware constants: v5e-class chip.
+permute 1x). Hardware constants: the dry-run targets a v5e chip.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
 
-# v5e-class constants (from the assignment)
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Peak rates of one chip, with where they were published."""
+    flops: float       # bf16 FLOP/s per chip
+    hbm_bw: float      # HBM bytes/s per chip
+    source: str
+
+
+#: per-chip peaks keyed by ``jax.Device.device_kind``. A device that is
+#: not listed (the CPU included) gets no roofline fraction.
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        197e12, 819e9,
+        'Google Cloud TPU docs, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM'),
+}
+
+# the dry-run compiles for a v5e (launch/dryrun.py)
+PEAK_FLOPS = PEAKS["TPU v5 lite"].flops
+HBM_BW = PEAKS["TPU v5 lite"].hbm_bw
 LINK_BW = 50e9               # bytes/s per ICI link (1 link assumed per hop)
 
 _DTYPE_BYTES = {
@@ -144,21 +161,31 @@ class Roofline:
         }
 
 
-def kernel_roofline(flops: float, nbytes: float, wall_s: float) -> dict:
+def kernel_roofline(flops: float, nbytes: float, wall_s: float, *,
+                    device_kind: str | None = None) -> dict:
     """Single-kernel roofline terms from host-side launch accounting.
 
     ``flops``/``nbytes`` are the launch path's analytic estimates (see
     ``obs/kerneltel.py`` per-site models), ``wall_s`` the measured
     launch-to-host-sync wall. ``roofline_fraction`` is the fraction of
     the roofline-implied minimum time actually achieved —
-    ``max(t_compute, t_memory) / wall`` against the v5e-class constants
-    above — the per-kernel score ``benchmarks/table10_observability.py``
-    publishes so efficiency regressions are visible in CI.
+    ``max(t_compute, t_memory) / wall`` against the :data:`PEAKS` entry
+    of ``device_kind`` (default: the first JAX device's). For a device
+    with no entry every term is None: there is no peak to divide by.
     """
-    t_compute = flops / PEAK_FLOPS
-    t_memory = nbytes / HBM_BW
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    peaks = PEAKS.get(device_kind)
+    if peaks is None:
+        return {"device_kind": device_kind, "t_compute_s": None,
+                "t_memory_s": None, "dominant": None,
+                "roofline_fraction": None}
+    t_compute = flops / peaks.flops
+    t_memory = nbytes / peaks.hbm_bw
     t_min = max(t_compute, t_memory)
     return {
+        "device_kind": device_kind,
         "t_compute_s": t_compute,
         "t_memory_s": t_memory,
         "dominant": "compute" if t_compute >= t_memory else "memory",

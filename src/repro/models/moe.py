@@ -166,7 +166,6 @@ def apply_moe_sharded(p: dict, x: jax.Array, cfg, *, no_drop: bool = False):
     completes the partial contraction."""
     import math
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.sharding.rules import current_mesh
     mesh = current_mesh()
     if mesh is None or "model" not in (mesh.axis_names or ()):
@@ -201,7 +200,7 @@ def apply_moe_sharded(p: dict, x: jax.Array, cfg, *, no_drop: bool = False):
             aux = jax.lax.pmean(aux, a)
         return y.reshape(x_loc.shape), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, None, None), P(None, None), w_spec,
                   (w_spec if cfg.mlp_gated else P()), wo_spec),

@@ -14,7 +14,8 @@ arithmetic (documented at each call site), not HLO measurements — they
 are the numerator of the roofline model in ``launch/roofline.py``:
 ``snapshot()`` derives each kernel's achieved GB/s, GFLOP/s, and
 ``roofline_fraction`` (roofline-implied minimum time / achieved wall,
-against the v5e-class constants), which
+against the running chip's peaks; None on a device with no entry in
+``launch/roofline.PEAKS``, the CPU included), which
 ``benchmarks/table10_observability.py`` writes into
 ``BENCH_results.json`` so kernel efficiency regressions gate CI.
 

@@ -90,7 +90,6 @@ def test_grad_compress_cross_pod_psum():
     out = run_with_devices("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.train import grad_compress
 
         devs = np.asarray(jax.devices()).reshape(2, 4)
@@ -102,7 +101,7 @@ def test_grad_compress_cross_pod_psum():
             out = grad_compress.podwise_mean(deq, 'pod')
             return out['w'], e['w']
 
-        f = shard_map(body, mesh=mesh, in_specs=(P('pod'), P('pod')),
+        f = jax.shard_map(body, mesh=mesh, in_specs=(P('pod'), P('pod')),
                       out_specs=(P('pod'), P('pod')))
         e0 = jnp.zeros((2, 64))
         out, e = f(g, e0)

@@ -45,9 +45,15 @@ def test_tile_env_override_wins(tile_cache, monkeypatch):
     launch.record_winner("batched_select", 8192, 4096)
     monkeypatch.setenv(launch.ENV_PREFIX + "BATCHED_SELECT", "1024")
     assert launch.tile_for("batched_select", n=5000) == 1024
-    # malformed override falls through to the cached winner
-    monkeypatch.setenv(launch.ENV_PREFIX + "BATCHED_SELECT", "zero")
-    assert launch.tile_for("batched_select", n=5000) == 4096
+    # malformed overrides, and tiles the TPU compiler would refuse for the
+    # kernel's blocks, are rejected before any launch is built
+    for bad in ("zero", "0", "-128", "1000"):
+        monkeypatch.setenv(launch.ENV_PREFIX + "BATCHED_SELECT", bad)
+        with pytest.raises(ValueError, match="multiple of 128"):
+            launch.tile_for("batched_select", n=5000)
+    monkeypatch.setenv(launch.ENV_PREFIX + "SHARD_ROUTE", "512")
+    with pytest.raises(ValueError, match="multiple of 1024"):
+        launch.tile_for("shard_route", n=5000)
     monkeypatch.delenv(launch.ENV_PREFIX + "BATCHED_SELECT")
     assert launch.tile_for("batched_select", n=5000) == 4096
     # other buckets still see the built-in default
@@ -60,33 +66,52 @@ def test_sweep_records_and_caches(tile_cache):
 
     def bench(tile):
         calls.append(tile)
-        return 1.0 if tile != 256 else 0.5
+        return 1.0 if tile != 2048 else 0.5
 
+    # candidates the chip would refuse never reach the bench
+    with pytest.raises(ValueError, match="refuses"):
+        launch.sweep("shard_route", bench, n=900, candidates=(512, 1024))
+    assert calls == []
     res = launch.sweep("shard_route", bench, n=900,
-                       candidates=(256, 512, 1024))
-    assert res["tile"] == 256 and not res["cached"]
+                       candidates=(256 * 4, 512 * 4, 1024 * 4))
+    assert res["tile"] == 2048 and not res["cached"]
     assert res["bucket"] == 1024
-    assert sorted(calls) == [256, 512, 1024]
+    assert sorted(calls) == [1024, 2048, 4096]
     # winner persisted to the env-pointed file...
     with open(tile_cache) as f:
         disk = json.load(f)
     assert any(k.startswith("shard_route/") and k.endswith("/b1024")
                for k in disk)
     # ...the serving path resolves it, and a repeat sweep is a cache read
-    assert launch.tile_for("shard_route", n=900) == 256
+    assert launch.tile_for("shard_route", n=900) == 2048
     calls.clear()
     res2 = launch.sweep("shard_route", bench, n=1000)
-    assert res2["cached"] and res2["tile"] == 256 and calls == []
+    assert res2["cached"] and res2["tile"] == 2048 and calls == []
     # force=True re-runs even with a winner on disk
     res3 = launch.sweep("shard_route", bench, n=900,
-                        candidates=(256, 512), force=True)
-    assert not res3["cached"] and calls == [256, 512]
+                        candidates=(1024, 2048), force=True)
+    assert not res3["cached"] and calls == [1024, 2048]
 
 
-def test_winner_cache_survives_reset(tile_cache):
+def test_winner_cache_survives_reset(tile_cache, monkeypatch):
     launch.record_winner("delta_codec", 2048, 1024)
     launch.reset_cache()  # drop the mirror: must re-read from disk
     assert launch.tile_for("delta_codec", n=1500) == 1024
+    # a cached winner the chip would refuse falls back to the built-in
+    launch.record_winner("shard_route", 2048, 512)
+    assert launch.tile_for("shard_route", n=1500) \
+        == launch.DEFAULT_TILES["shard_route"]
+    # without GESTORE_TILE_CACHE nothing is read from or written to disk:
+    # the built-in tiles are the only launch state
+    monkeypatch.delenv(launch.CACHE_ENV)
+    launch.reset_cache()
+    assert launch.cache_path() is None
+    assert launch.tile_for("delta_codec", n=1500) \
+        == launch.DEFAULT_TILES["delta_codec"]
+    launch.record_winner("delta_codec", 2048, 256)
+    assert launch.tile_for("delta_codec", n=1500) == 256
+    with open(tile_cache) as f:
+        assert json.load(f)["delta_codec/cpu/b2048"] == 1024
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from repro.core.store import FieldSchema, VersionedStore
+from repro.launch.roofline import PEAKS, kernel_roofline
 from repro.obs import (FlightRecorder, Histogram, MetricsRegistry, RECORDER,
                        StageTimer, current_span, current_trace_id,
                        new_trace_id, span)
@@ -196,10 +197,16 @@ def test_kernel_telemetry_aggregates_and_derives_roofline():
     snap = tel.snapshot()["k"]
     assert snap["calls"] == 2
     assert snap["bytes"] == 2e6 and snap["flops"] == 4e6
-    # analytic-estimate fraction: positive, can exceed 1.0 when the wall
-    # of a trivial region undercuts the modeled roofline minimum
-    assert snap["roofline_fraction"] > 0.0
-    assert snap["dominant"] in ("compute", "memory")
+    # the CPU has no entry in the peak table: no roofline is reported
+    assert snap["device_kind"] not in PEAKS
+    assert snap["roofline_fraction"] is None and snap["dominant"] is None
+    # a listed chip divides by its own peaks: 1 MB at 819 GB/s is the
+    # memory-bound minimum, so a 2x slower wall is half the roofline
+    kind = "TPU v5 lite"
+    t_min = 1e6 / PEAKS[kind].hbm_bw
+    r = kernel_roofline(2e6, 1e6, 2 * t_min, device_kind=kind)
+    assert r["dominant"] == "memory"
+    assert r["roofline_fraction"] == pytest.approx(0.5)
 
 
 def test_kernel_telemetry_skips_failed_launches():
