@@ -27,6 +27,20 @@ One release timestamp commits atomically at ``finish()``; the journal is
 the only mid-release durability (see ``IngestJournal`` for why the
 store's own incremental save cannot checkpoint half a release).
 
+Tracing: the thread that runs ``ingest_release`` drives the device, and
+its work is a sequence of ``StageTimer`` leaves (``repro.obs.trace``),
+each a ``gestore.ingest.<leaf>`` profiler annotation:
+``ingest.wait_parse`` (waiting for the next parsed batch: the queue, a
+parse future, or the parse itself in inline mode), ``ingest.journal``
+(the pre-release save and journal start, each journaled chunk, the
+journal flush; replayed chunks' reads), ``ingest.route``,
+``ingest.fingerprint`` and ``ingest.dispatch`` (inside
+``session.apply``, see ``ShardedReleaseSession.apply``), and
+``ingest.commit`` (``session.finish`` with its barrier on the shard
+workers, the post-commit save and the journal's removal). The reader
+and shard-worker threads open no leaf; their time is the
+``ingest.parse_wall`` and ``ingest.shard_apply_wall`` histograms.
+
 Backpressure: when the serving tier's ``TieredStorePool.pressure()``
 (or any ``pressure_fn``) exceeds ``max_pressure``, the apply loop waits —
 ingest yields to query traffic instead of thrashing the pool.
@@ -46,7 +60,7 @@ from typing import Callable, Iterable, Iterator
 
 import numpy as np
 
-from repro.obs import RECORDER, REGISTRY, get_logger, span
+from repro.obs import RECORDER, REGISTRY, StageTimer, get_logger, span
 
 from .plugins import FileParser
 from .store import VersionInfo
@@ -276,14 +290,60 @@ def _bounded_put(q: queue.Queue, item, stop: threading.Event) -> bool:
 def _producer(gen, q: queue.Queue, stop: threading.Event) -> None:
     """Pipelined stage-2 wrapper: drain the batch generator into the
     bounded queue from a reader thread. Items: ("batch", payload, off, n),
-    then ("done"|"error", payload, None, 0)."""
+    then ("done"|"error", payload, None, 0). The reader's wall per batch
+    (the parse, or with parse workers the entry split) goes to the
+    ``ingest.parse_wall`` histogram."""
+    h_parse = REGISTRY.histogram("ingest.parse_wall")
     try:
+        t0 = time.perf_counter()
         for payload, off, n in gen:
+            h_parse.record(time.perf_counter() - t0)
             if not _bounded_put(q, ("batch", payload, off, n), stop):
                 return
+            t0 = time.perf_counter()
         _bounded_put(q, ("done", None, None, 0), stop)
     except BaseException as e:  # noqa: BLE001 — forwarded to the consumer
         _bounded_put(q, ("error", e, None, 0), stop)
+
+
+def _open_journal(journal_dir: str, store, ts: int, label: str,
+                  full_release: bool, store_dir: str | None,
+                  track_offsets: bool):
+    """The release's journal: resumed when one for this release exists
+    (``(journal, chunks to replay, source offset, records to skip)``), or
+    begun afresh after a durable pre-release save; None when a resume
+    finds the release already committed (its journal is cleared)."""
+    from repro.ft.checkpoint import IngestJournal
+
+    j = IngestJournal.open(journal_dir)
+    if (j is not None and j.meta["ts"] == ts
+            and j.meta["store"] == store.name):
+        if store.last_ts >= ts:
+            # the crash landed after finish(): release committed,
+            # journal just never got cleaned up
+            j.clear()
+            return None
+        wm = store_watermark(store)
+        if wm != j.meta["watermark"]:
+            raise IngestResumeError(
+                f"ingest journal {journal_dir} was written against a "
+                f"different store state (journal {j.meta['watermark']} "
+                f"vs store {wm}); reload the store from its directory "
+                "or clear the journal")
+        off = j.resume_offset()
+        _LOG.info("ingest resume: %d journaled chunks, offset %s",
+                  len(j.chunks), off)
+        if off is None or not track_offsets:
+            return j, list(j.chunks), 0, j.entries_applied()
+        return j, list(j.chunks), off, 0
+    if j is not None:
+        j.clear()  # stale journal for some other release
+    if store_dir is not None:
+        store.save(store_dir)  # durable pre-release state
+    return IngestJournal.begin(
+        journal_dir, store=store.name, ts=ts, label=label,
+        full_release=full_release,
+        watermark=store_watermark(store)), [], 0, 0
 
 
 # -- the engine --------------------------------------------------------------
@@ -329,8 +389,6 @@ def ingest_release(store, source, parser: FileParser, ts: int, *,
       ValueError: non-monotonic ``ts`` or a mid-stream validation failure
         (already-applied chunks stay applied; the journal resumes them).
     """
-    from repro.ft.checkpoint import IngestJournal
-
     cfg = config or IngestConfig()
     rep = IngestReport(ts=int(ts), label=label or str(ts))
     t_run = time.perf_counter()
@@ -342,41 +400,14 @@ def ingest_release(store, source, parser: FileParser, ts: int, *,
     start_offset = 0
     skip_records = 0
     if journal_dir is not None:
-        j = IngestJournal.open(journal_dir)
-        if (j is not None and j.meta["ts"] == int(ts)
-                and j.meta["store"] == store.name):
-            if store.last_ts >= int(ts):
-                # the crash landed after finish(): release committed,
-                # journal just never got cleaned up
-                j.clear()
-                rep.already_committed = True
-                rep.wall_s = time.perf_counter() - t_run
-                return rep
-            wm = store_watermark(store)
-            if wm != j.meta["watermark"]:
-                raise IngestResumeError(
-                    f"ingest journal {journal_dir} was written against a "
-                    f"different store state (journal {j.meta['watermark']} "
-                    f"vs store {wm}); reload the store from its directory "
-                    "or clear the journal")
-            journal = j
-            replay = list(j.chunks)
-            off = j.resume_offset()
-            if off is None or not track_offsets:
-                skip_records = j.entries_applied()
-                start_offset = 0
-            else:
-                start_offset = off
-            _LOG.info("ingest resume: %d journaled chunks, offset %s",
-                      len(replay), off)
-        else:
-            if j is not None:
-                j.clear()  # stale journal for some other release
-            if store_dir is not None:
-                store.save(store_dir)  # durable pre-release state
-            journal = IngestJournal.begin(
-                journal_dir, store=store.name, ts=int(ts), label=label,
-                full_release=full_release, watermark=store_watermark(store))
+        with StageTimer(None, "ingest", "journal"):
+            opened = _open_journal(journal_dir, store, int(ts), label,
+                                   full_release, store_dir, track_offsets)
+        if opened is None:
+            rep.already_committed = True
+            rep.wall_s = time.perf_counter() - t_run
+            return rep
+        journal, replay, start_offset, skip_records = opened
 
     # pre-declare the parser schema: chunk-local inference must never get
     # to pick a narrower dtype than the whole file would
@@ -409,7 +440,8 @@ def ingest_release(store, source, parser: FileParser, ts: int, *,
         try:
             # -- replay journaled chunks (no re-parse) ----------------------
             for c in replay:
-                keys, table = journal.load_chunk(c["idx"])
+                with StageTimer(None, "ingest", "journal"):
+                    keys, table = journal.load_chunk(c["idx"])
                 wait_pressure()
                 t0 = time.perf_counter()
                 session.apply(keys, table)
@@ -433,14 +465,16 @@ def ingest_release(store, source, parser: FileParser, ts: int, *,
 
             def apply_batch(payload, off) -> None:
                 if isinstance(payload, Future):
-                    keys, table, off = payload.result()
+                    with StageTimer(None, "ingest", "wait_parse"):
+                        keys, table, off = payload.result()
                 else:
                     keys, table = payload
                 wait_pressure()
                 if journal is not None:
-                    journal.record_chunk(
-                        keys, table, source_offset=off,
-                        flush=(rep.n_chunks % cfg.manifest_every == 0))
+                    with StageTimer(None, "ingest", "journal"):
+                        journal.record_chunk(
+                            keys, table, source_offset=off,
+                            flush=(rep.n_chunks % cfg.manifest_every == 0))
                     c_ckpt.inc()
                     rep.checkpoint_writes += 1
                 t0 = time.perf_counter()
@@ -464,7 +498,8 @@ def ingest_release(store, source, parser: FileParser, ts: int, *,
                     prod.start()
                     try:
                         while True:
-                            kind, payload, off, _n = q.get()
+                            with StageTimer(None, "ingest", "wait_parse"):
+                                kind, payload, off, _n = q.get()
                             if kind == "done":
                                 break
                             if kind == "error":
@@ -474,15 +509,23 @@ def ingest_release(store, source, parser: FileParser, ts: int, *,
                         stop.set()
                         prod.join(timeout=5.0)
                 else:
-                    # inline mode: no reader thread to overlap with
-                    for payload, off, _n in gen:
-                        apply_batch(payload, off)
+                    # inline mode: no reader thread to overlap with, so
+                    # the parse itself is what the wave waits for
+                    batches = iter(gen)
+                    while True:
+                        with StageTimer(None, "ingest", "wait_parse"):
+                            item = next(batches, None)
+                        if item is None:
+                            break
+                        apply_batch(item[0], item[1])
             finally:
                 if pool is not None:
                     pool.shutdown(wait=False, cancel_futures=True)
             if journal is not None:
-                journal.flush()
-            rep.info = session.finish()
+                with StageTimer(None, "ingest", "journal"):
+                    journal.flush()
+            with StageTimer(None, "ingest", "commit"):
+                rep.info = session.finish()
         except BaseException as e:  # noqa: BLE001 — abort telemetry, re-raise
             RECORDER.record("ingest_abort", trace=sp.trace_id,
                             store=store.name, ts=int(ts),
@@ -491,9 +534,10 @@ def ingest_release(store, source, parser: FileParser, ts: int, *,
             raise
 
     if store_dir is not None:
-        store.save(store_dir)  # release cells reach disk exactly once
-        if journal is not None:
-            journal.clear()  # durable => the journal has served its purpose
+        with StageTimer(None, "ingest", "commit"):
+            store.save(store_dir)  # release cells reach disk exactly once
+            if journal is not None:
+                journal.clear()  # durable => the journal has served its purpose
     rep.wall_s = time.perf_counter() - t_run
     return rep
 

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from repro.kernels import launch as klaunch
 from repro.kernels.batched_select import scan_bucket, stacked_boundary_select
 from repro.launch.mesh import make_shard_mesh
-from repro.obs import kerneltel
+from repro.obs.trace import StageTimer
 
 from .store import _SuperLog, _clamp_ts
 
@@ -169,20 +169,8 @@ class PlacedSuperLog:
         q_pad = klaunch.pow2_bucket(q, floor=8)
         qs_in = qs if q_pad == q else np.concatenate(
             [qs, np.full(q_pad - q, qs[-1], np.int32)])
-        s, cmax = self._ts.shape
-        bmax = self._bnd.shape[1]
-        # stacked traffic model: logical counts the real per-shard cells
-        # and boundaries; padded counts the bucketed (S, Cmax)/(S, Q, Bmax)
-        # stacked shapes that actually move
-        b_sum = sum(self.b_widths)
-        with kerneltel.launch("batched_select",
-                              nbytes=4 * (self.n_cells + q * self.n_cells
-                                          + 2 * q * b_sum),
-                              flops=2 * q * self.n_cells,
-                              padded_nbytes=4 * (s * cmax + s * q_pad * cmax
-                                                 + 2 * s * q_pad * bmax)):
-            out = np.asarray(stacked_boundary_select(
-                self._ts, jnp.asarray(qs_in), self._bnd, mesh=self.mesh))
+        out = np.asarray(stacked_boundary_select(
+            self._ts, jnp.asarray(qs_in), self._bnd, mesh=self.mesh))
         return [out[i, :q, : w] for i, w in enumerate(self.b_widths)]
 
     # -- fused cross-shard value gathers --------------------------------------
@@ -216,22 +204,30 @@ class PlacedSuperLog:
         return self._fused_field(name, superlogs)[1]
 
     def take_cells(self, name: str, idx: np.ndarray, keep: np.ndarray,
-                   lens, superlogs) -> list[np.ndarray]:
+                   lens, superlogs, trace: dict | None = None
+                   ) -> list[np.ndarray]:
         """One fused device gather for a whole wave: ``idx`` holds global
         cell positions (already permuted into every query's final merged
         row order, queries back to back with per-query ``lens``) and
         ``keep`` masks rows whose value must be zeroed (no cell at the
         query time / deleted rows) — the same semantics as
-        ``_SuperLog.gather_finalize``, minus the host-side mutation."""
-        dev, _offs, total, width, dtype = self._fused_field(name, superlogs)
-        if dev is None or len(idx) == 0:
-            return [np.zeros((int(n), width), dtype) for n in lens]
-        out = np.asarray(jnp.where(
-            jnp.asarray(keep)[:, None],
-            jnp.take(dev, jnp.asarray(np.clip(idx, 0, total - 1)), axis=0),
-            jnp.zeros((), dev.dtype)))
-        cum = np.cumsum([0] + list(lens))
-        return [out[cum[i]: cum[i + 1]] for i in range(len(lens))]
+        ``_SuperLog.gather_finalize``, minus the host-side mutation. The
+        device take is the ``gather.take`` leaf, its copy to the host
+        ``gather.copy``."""
+        with StageTimer(trace, "gather", "take"):
+            dev, _offs, total, width, dtype = self._fused_field(name,
+                                                                superlogs)
+            if dev is None or len(idx) == 0:
+                return [np.zeros((int(n), width), dtype) for n in lens]
+            out = jnp.where(
+                jnp.asarray(keep)[:, None],
+                jnp.take(dev, jnp.asarray(np.clip(idx, 0, total - 1)),
+                         axis=0),
+                jnp.zeros((), dev.dtype))
+        with StageTimer(trace, "gather", "copy"):
+            out = np.asarray(out)
+            cum = np.cumsum([0] + list(lens))
+            return [out[cum[i]: cum[i + 1]] for i in range(len(lens))]
 
     def exists_matrices(self, bcums, superlogs) -> list[tuple]:
         """Per-shard ``(alive, ever)`` — ``_SuperLog.exists_matrix`` for

@@ -74,13 +74,13 @@ import hashlib
 import io
 import json
 import os
+import time
 from typing import TYPE_CHECKING, Sequence
 
 import numpy as np
 
 from repro.kernels.delta_codec import chain_pack, chain_unpack
-from repro.obs import RECORDER, REGISTRY
-from repro.obs.trace import StageTimer
+from repro.obs import RECORDER, REGISTRY, current_span
 
 if TYPE_CHECKING:  # avoid a circular import; store.py imports us lazily
     from .store import VersionedStore
@@ -151,6 +151,14 @@ def _fsync_dir(path: str) -> None:
 
 # -- segment file I/O ---------------------------------------------------------
 
+def count_written(nbytes: int) -> int:
+    """Add ``nbytes`` to the ``storage.bytes_written`` counter, which
+    counts every byte of every file the store and the ingest journal
+    write; returns ``nbytes``."""
+    REGISTRY.counter("storage.bytes_written").inc(nbytes)
+    return nbytes
+
+
 def write_segment(root: str, field: str, rows: np.ndarray, tss: np.ndarray,
                   vals: np.ndarray, *, kind: str = "delta",
                   tag: str = "") -> tuple[SegmentMeta, int]:
@@ -183,6 +191,7 @@ def write_segment(root: str, field: str, rows: np.ndarray, tss: np.ndarray,
     tmp = path + ".tmp.npz"
     with open(tmp, "wb") as f:
         f.write(blob)
+        count_written(len(blob))
         # tmp+rename alone only survives application crashes; a power
         # failure can leave the renamed file empty unless its data was
         # synced first. fsync errors (e.g. EIO) must abort the save.
@@ -265,16 +274,23 @@ class SegmentHandle:
         # instrument the CALLER, not read_segment itself: fault-injection
         # tests replace the module-level read_segment wholesale, and an
         # injected failure must still land in the flight recorder with
-        # the active trace id attached
+        # the active trace id attached. Timed on the host clock only (no
+        # profiler leaf): a lazy read runs inside a leaf such as
+        # scan.build, or on a shard worker, and leaves never nest
+        t0 = time.perf_counter()
         try:
-            with StageTimer(None, "segment_read"):
-                return read_segment(self.root, self.seg, self.dtype,
-                                    self.width)
+            return read_segment(self.root, self.seg, self.dtype, self.width)
         except Exception as e:  # noqa: BLE001 — recorded, then re-raised
             REGISTRY.counter("segments.read_errors").inc()
             RECORDER.record("segment_read_error", path=self.seg.path,
                             root=self.root, error=repr(e))
             raise
+        finally:
+            dt = time.perf_counter() - t0
+            REGISTRY.histogram("stage.segment_read").record(dt)
+            sp = current_span()
+            if sp is not None:
+                sp.add_stage("segment_read", dt)
 
 
 # -- manifest I/O -------------------------------------------------------------
@@ -303,7 +319,7 @@ def write_manifest(root: str, man: dict) -> int:
         os.fsync(f.fileno())
     os.replace(tmp, p)
     _fsync_dir(root)
-    return os.path.getsize(p)
+    return count_written(os.path.getsize(p))
 
 
 def _index_name(man: dict) -> str:
@@ -353,6 +369,7 @@ def _append_segment_index(root: str, man: dict,
         f.truncate(committed_bytes)
         f.seek(committed_bytes)
         f.write(data.encode())
+        count_written(len(data.encode()))
         f.flush()
         os.fsync(f.fileno())
     return committed_bytes + len(data.encode())
@@ -377,7 +394,7 @@ def _write_new_index_generation(root: str, gen: int,
         os.fsync(f.fileno())
     os.replace(tmp, p)
     _fsync_dir(root)
-    return name, len(data.encode())
+    return name, count_written(len(data.encode()))
 
 
 def _manifest_payload(store: "VersionedStore", saved_through: int, *,

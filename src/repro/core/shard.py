@@ -48,6 +48,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Mapping, Sequence
 
@@ -56,6 +57,7 @@ import numpy as np
 from repro.kernels import ops as kops
 from repro.kernels.shard_route import (ROUTING_VERSION, merge_shard_rows,
                                        route_keys)
+from repro.obs import REGISTRY, StageTimer
 
 from . import store as store_mod
 from .placement import PlacedSuperLog, ShardPlacement, plan_placement
@@ -87,7 +89,7 @@ def read_shard_manifest(root: str) -> dict | None:
 
 def _write_shard_manifest(root: str, man: dict) -> int:
     """Atomically commit the shard manifest; returns its byte size."""
-    from .segments import _fsync_dir
+    from .segments import _fsync_dir, count_written
     p = os.path.join(root, SHARD_MANIFEST_NAME)
     tmp = p + ".tmp"
     with open(tmp, "w") as f:
@@ -96,7 +98,7 @@ def _write_shard_manifest(root: str, man: dict) -> int:
         os.fsync(f.fileno())
     os.replace(tmp, p)
     _fsync_dir(root)
-    return os.path.getsize(p)
+    return count_written(os.path.getsize(p))
 
 
 def is_sharded_dir(path: str) -> bool:
@@ -563,11 +565,8 @@ class ShardedStore:
         order — no per-shard intermediate views, no re-concatenation. The
         math per element is exactly ``VersionedStore.get_versions`` + the
         facade merge — byte-identical to the serial loop."""
-        with store_mod._StageTimer(trace, "scan"):
-            placed, sls = self._placed_superlog()
-            nq, ns = len(uniq), self.n_shards
-            bcums = placed.boundary_cums(uniq)
-            ex = placed.exists_matrices(bcums, sls)
+        placed, sls, bcums, ex = self._placed_scan(uniq, trace)
+        nq, ns = len(uniq), self.n_shards
         store_mod._check_cancel(cancel)
         # per-shard flat selections over ALL queries (row-major (qi, row)
         # nonzero order == the per-query loop order the serial path uses)
@@ -596,8 +595,8 @@ class ShardedStore:
         rows_q = np.split(rows_all, np.cumsum(lens_q)[:-1])
         values_q: list[dict] = [{} for _ in range(nq)]
         store_mod._check_cancel(cancel)
-        with store_mod._StageTimer(trace, "gather"):
-            for name in fields:
+        for name in fields:
+            with store_mod._StageTimer(trace, "gather", "take"):
                 offs = placed.field_offsets(name, sls)
                 iparts, kparts = [], []
                 for s in range(ns):
@@ -606,10 +605,10 @@ class ShardedStore:
                     iparts.append(offs[s] + np.clip(
                         f.ptr[sel_cat[s]] + c - 1, 0, max(f.n_cells - 1, 0)))
                     kparts.append(c > 0)
-                for qi, v in enumerate(placed.take_cells(
-                        name, np.concatenate(iparts)[perm],
-                        np.concatenate(kparts)[perm], lens_q, sls)):
-                    values_q[qi][name] = v
+            for qi, v in enumerate(placed.take_cells(
+                    name, np.concatenate(iparts)[perm],
+                    np.concatenate(kparts)[perm], lens_q, sls, trace)):
+                values_q[qi][name] = v
         with store_mod._StageTimer(trace, "materialize"):
             return [VersionView(ts=t,
                                 keys=[self.row_keys[r] for r in rows_q[qi]],
@@ -617,10 +616,23 @@ class ShardedStore:
                                 values=values_q[qi])
                     for qi, t in enumerate(uniq)]
 
+    def _placed_scan(self, uniq, trace):
+        """The stacked scan stage as its leaves (``scan.build``,
+        ``scan.select``, ``scan.exists``): (placed superlog, per-shard
+        superlogs, per-shard boundary cumsums, per-shard (alive, ever))."""
+        with store_mod._StageTimer(trace, "scan", "build"):
+            placed, sls = self._placed_superlog()
+        with store_mod._StageTimer(trace, "scan", "select"):
+            bcums = placed.boundary_cums(uniq)
+        with store_mod._StageTimer(trace, "scan", "exists"):
+            return placed, sls, bcums, placed.exists_matrices(bcums, sls)
+
     def get_increments(self, pairs: Sequence[tuple[Timestamp, Timestamp]], *,
                        significant_fields: Sequence[str] | None = None,
-                       fields: Sequence[str] | None = None) -> list[Increment]:
-        """Batched get_increments, scatter-gathered like get_versions."""
+                       fields: Sequence[str] | None = None,
+                       trace: dict | None = None) -> list[Increment]:
+        """Batched get_increments, scatter-gathered like get_versions;
+        ``trace`` follows ``VersionedStore.get_increments``."""
         sig = (list(significant_fields) if significant_fields is not None
                else list(self.schema))
         out_fields = list(fields) if fields is not None else list(self.schema)
@@ -630,25 +642,27 @@ class ShardedStore:
         upairs = list(dict.fromkeys(pairs))
         if self._use_parallel(len(upairs)):
             by_p = dict(zip(upairs, self._get_increments_parallel(
-                upairs, sig, out_fields)))
+                upairs, sig, out_fields, trace)))
             return [by_p[p] for p in pairs]
         per_shard = [self.shard(s).get_increments(
-            upairs, significant_fields=sig, fields=out_fields)
+            upairs, significant_fields=sig, fields=out_fields, trace=trace)
             for s in range(self.n_shards)]
-        by_pair: dict[tuple[int, int], Increment] = {}
-        for qi, (t0, t1) in enumerate(upairs):
-            incs = [per_shard[s][qi] for s in range(self.n_shards)]
-            rows, order = merge_shard_rows(
-                [self._shard_rows(s)[inc.row_idx]
-                 for s, inc in enumerate(incs)])
-            kind = np.concatenate([inc.kind for inc in incs])[order]
-            values = {
-                name: np.concatenate([inc.values[name] for inc in incs])[order]
-                for name in out_fields}
-            by_pair[(t0, t1)] = Increment(
-                t0=t0, t1=t1, keys=[self.row_keys[r] for r in rows],
-                row_idx=rows.astype(np.int32), kind=kind, values=values)
-        return [by_pair[p] for p in pairs]
+        with store_mod._StageTimer(trace, "materialize"):
+            by_pair: dict[tuple[int, int], Increment] = {}
+            for qi, (t0, t1) in enumerate(upairs):
+                incs = [per_shard[s][qi] for s in range(self.n_shards)]
+                rows, order = merge_shard_rows(
+                    [self._shard_rows(s)[inc.row_idx]
+                     for s, inc in enumerate(incs)])
+                kind = np.concatenate([inc.kind for inc in incs])[order]
+                values = {
+                    name: np.concatenate([inc.values[name]
+                                          for inc in incs])[order]
+                    for name in out_fields}
+                by_pair[(t0, t1)] = Increment(
+                    t0=t0, t1=t1, keys=[self.row_keys[r] for r in rows],
+                    row_idx=rows.astype(np.int32), kind=kind, values=values)
+            return [by_pair[p] for p in pairs]
 
     def get_increment(self, t0: Timestamp, t1: Timestamp, *,
                       significant_fields: Sequence[str] | None = None,
@@ -657,8 +671,8 @@ class ShardedStore:
             [(t0, t1)], significant_fields=significant_fields,
             fields=fields)[0]
 
-    def _get_increments_parallel(self, upairs, sig,
-                                 out_fields) -> list[Increment]:
+    def _get_increments_parallel(self, upairs, sig, out_fields,
+                                 trace=None) -> list[Increment]:
         """MERGED increments for the unique windows from ONE stacked launch
         over the unique endpoints — the device-parallel twin of the serial
         per-shard ``get_increments`` loop + facade merge (same math, same
@@ -667,66 +681,69 @@ class ShardedStore:
         deleted-row zeroing folded into the gather mask."""
         uniq = list(dict.fromkeys(t for p in upairs for t in p))
         q_of = {t: i for i, t in enumerate(uniq)}
-        placed, sls = self._placed_superlog()
+        placed, sls, bcums, ex = self._placed_scan(uniq, trace)
         np_ct, ns = len(upairs), self.n_shards
-        bcums = placed.boundary_cums(uniq)
-        ex = placed.exists_matrices(bcums, sls)
-        names = list(dict.fromkeys(sig + out_fields))
-        cnt = [{name: sls[s].counts(name, bcums[s]) for name in names}
-               for s in range(ns)]
-        i0_arr = np.asarray([q_of[t0] for t0, _ in upairs], np.intp)
-        i1_arr = np.asarray([q_of[t1] for _, t1 in upairs], np.intp)
-        # per-shard flat (pair, row) selections + kinds, all pairs at once
-        # ((pi, row) nonzero order == the serial per-pair loop order)
-        sel_cat, pi_cat, kind_cat = [], [], []
-        for s in range(ns):
-            exists = ex[s][0]
-            changed = np.zeros((np_ct, self._shards[s].n_rows), bool)
-            for name in sig:
-                changed |= (cnt[s][name][i1_arr] - cnt[s][name][i0_arr]) > 0
-            e0, e1 = exists[i0_arr], exists[i1_arr]
-            new = e1 & ~e0
-            deleted = e0 & ~e1
-            updated = e1 & e0 & changed
-            pis, rr = np.nonzero(new | deleted | updated)
-            kind = np.zeros(len(rr), np.int8)  # zeros == KIND_NEW
-            kind[updated[pis, rr]] = KIND_UPDATED
-            kind[deleted[pis, rr]] = KIND_DELETED
-            sel_cat.append(rr)
-            pi_cat.append(pis)
-            kind_cat.append(kind)
-        # one stable sort merges every pair's rows (see _get_versions_parallel)
-        big_pi = np.concatenate(pi_cat)
-        big_g = np.concatenate(
-            [self._shard_rows(s)[sel_cat[s]] for s in range(ns)])
-        perm = np.lexsort((big_g, big_pi))
-        rows_all = big_g[perm]
-        kind_all = np.concatenate(kind_cat)[perm]
-        lens_q = np.bincount(big_pi, minlength=np_ct)
-        cuts = np.cumsum(lens_q)[:-1]
-        rows_q = np.split(rows_all, cuts)
-        kind_q = np.split(kind_all, cuts)
-        not_deleted = kind_all != KIND_DELETED
+        with store_mod._StageTimer(trace, "diff"):
+            names = list(dict.fromkeys(sig + out_fields))
+            cnt = [{name: sls[s].counts(name, bcums[s]) for name in names}
+                   for s in range(ns)]
+            i0_arr = np.asarray([q_of[t0] for t0, _ in upairs], np.intp)
+            i1_arr = np.asarray([q_of[t1] for _, t1 in upairs], np.intp)
+            # per-shard flat (pair, row) selections + kinds, all pairs at once
+            # ((pi, row) nonzero order == the serial per-pair loop order)
+            sel_cat, pi_cat, kind_cat = [], [], []
+            for s in range(ns):
+                exists = ex[s][0]
+                changed = np.zeros((np_ct, self._shards[s].n_rows), bool)
+                for name in sig:
+                    changed |= (cnt[s][name][i1_arr]
+                                - cnt[s][name][i0_arr]) > 0
+                e0, e1 = exists[i0_arr], exists[i1_arr]
+                new = e1 & ~e0
+                deleted = e0 & ~e1
+                updated = e1 & e0 & changed
+                pis, rr = np.nonzero(new | deleted | updated)
+                kind = np.zeros(len(rr), np.int8)  # zeros == KIND_NEW
+                kind[updated[pis, rr]] = KIND_UPDATED
+                kind[deleted[pis, rr]] = KIND_DELETED
+                sel_cat.append(rr)
+                pi_cat.append(pis)
+                kind_cat.append(kind)
+            # one stable sort merges every pair's rows (as in
+            # _get_versions_parallel)
+            big_pi = np.concatenate(pi_cat)
+            big_g = np.concatenate(
+                [self._shard_rows(s)[sel_cat[s]] for s in range(ns)])
+            perm = np.lexsort((big_g, big_pi))
+            rows_all = big_g[perm]
+            kind_all = np.concatenate(kind_cat)[perm]
+            lens_q = np.bincount(big_pi, minlength=np_ct)
+            cuts = np.cumsum(lens_q)[:-1]
+            rows_q = np.split(rows_all, cuts)
+            kind_q = np.split(kind_all, cuts)
+            not_deleted = kind_all != KIND_DELETED
         values_q: list[dict] = [{} for _ in upairs]
         for name in out_fields:
-            offs = placed.field_offsets(name, sls)
-            iparts, kparts = [], []
-            for s in range(ns):
-                f = sls[s].fields[name]
-                c = cnt[s][name][i1_arr[pi_cat[s]], sel_cat[s]]
-                iparts.append(offs[s] + np.clip(
-                    f.ptr[sel_cat[s]] + c - 1, 0, max(f.n_cells - 1, 0)))
-                kparts.append(c > 0)
+            with store_mod._StageTimer(trace, "gather", "take"):
+                offs = placed.field_offsets(name, sls)
+                iparts, kparts = [], []
+                for s in range(ns):
+                    f = sls[s].fields[name]
+                    c = cnt[s][name][i1_arr[pi_cat[s]], sel_cat[s]]
+                    iparts.append(offs[s] + np.clip(
+                        f.ptr[sel_cat[s]] + c - 1, 0, max(f.n_cells - 1, 0)))
+                    kparts.append(c > 0)
             for qi, v in enumerate(placed.take_cells(
                     name, np.concatenate(iparts)[perm],
                     np.concatenate(kparts)[perm] & not_deleted,
-                    lens_q, sls)):
+                    lens_q, sls, trace)):
                 values_q[qi][name] = v
-        return [Increment(t0=t0, t1=t1,
-                          keys=[self.row_keys[r] for r in rows_q[qi]],
-                          row_idx=rows_q[qi].astype(np.int32),
-                          kind=kind_q[qi], values=values_q[qi])
-                for qi, (t0, t1) in enumerate(upairs)]
+        with store_mod._StageTimer(trace, "materialize"):
+            return [Increment(t0=t0, t1=t1,
+                              keys=[self.row_keys[r] for r in rows_q[qi]],
+                              row_idx=rows_q[qi].astype(np.int32),
+                              kind=kind_q[qi], values=values_q[qi])
+                    for qi, (t0, t1) in enumerate(upairs)]
 
     # -- compaction -----------------------------------------------------------
     def compact(self, before_ts: Timestamp, *, label: str = "",
@@ -961,10 +978,34 @@ class ShardedReleaseSession:
         """Route one chunk and apply its per-shard sub-chunks as one
         concurrent wave; returns the chunk entry count. Facade-level
         validation runs before any shard mutates (chunks already applied
-        stay applied — the ingest journal owns crash recovery)."""
+        stay applied — the ingest journal owns crash recovery).
+
+        On the calling (ingest) thread the chunk is the leaves
+        ``ingest.route`` (casts, ``shard_route``, row allocation),
+        ``ingest.fingerprint`` (the kernels and the copy of the digests)
+        and ``ingest.dispatch`` (sub-chunk slicing, submission to the
+        shard workers, surfacing their failures); the workers' own time is
+        the ``ingest.shard_apply_wall`` histogram."""
         if self._finished:
             raise RuntimeError("release session already finished")
-        self._drain(wait=False)  # propagate any earlier wave's failure
+        with StageTimer(None, "ingest", "dispatch"):
+            self._drain(wait=False)  # propagate any earlier wave's failure
+        with StageTimer(None, "ingest", "route"):
+            keys, arrays, sid = self._route_chunk(keys, table)
+        # fingerprint the whole chunk ONCE per field: one kernel launch
+        # each instead of n_shards small ones inside the sub-applies (the
+        # dominant per-wave fixed cost); shards slice the shared result
+        with StageTimer(None, "ingest", "fingerprint"):
+            fps = {name: kops.fingerprint_rows(arr)
+                   for name, arr in arrays.items()}
+        with StageTimer(None, "ingest", "dispatch"):
+            self._dispatch(keys, arrays, sid, fps, list(table))
+        self.n_entries += len(keys)
+        return len(keys)
+
+    def _route_chunk(self, keys, table):
+        """Validate and cast one chunk, route its keys to shards and
+        allocate their global rows; returns (keys, arrays, shard ids)."""
         st = self.store
         keys = _as_bytes(keys)
         new_fields: dict[str, FieldSchema] = {}
@@ -989,12 +1030,12 @@ class ShardedReleaseSession:
                 st.add_field(fs)
         sid = st._route(keys)
         st._alloc_rows(keys, sid)
-        # fingerprint the whole chunk ONCE per field: one kernel launch
-        # each instead of n_shards small ones inside the sub-applies (the
-        # dominant per-wave fixed cost); shards slice the shared result
-        fps = {name: kops.fingerprint_rows(arr)
-               for name, arr in arrays.items()}
-        names = list(table)
+        return keys, arrays, sid
+
+    def _dispatch(self, keys, arrays, sid, fps, names) -> None:
+        """Slice one routed chunk per shard and apply each sub-chunk on its
+        shard's worker (inline without workers)."""
+        st = self.store
         for s in range(st.n_shards):
             m = sid == s
             if not m.any():
@@ -1006,17 +1047,18 @@ class ShardedReleaseSession:
 
             def work(sh=sh, sess=sess, skeys=skeys, stable=stable,
                      sfps=sfps):
+                t0 = time.perf_counter()
                 # pre-read this shard's on-disk segments (corrupt segments
                 # raise here, before the shard mutates), then apply
                 sh.rebuild_heads([n for n in names if n in sh.fields])
                 sess.apply(skeys, stable, _precast=True, _fps=sfps)
+                REGISTRY.histogram("ingest.shard_apply_wall").record(
+                    time.perf_counter() - t0)
 
             if self._execs is not None:
                 self._futs.append(self._execs[s].submit(work))
             else:
                 work()
-        self.n_entries += len(keys)
-        return len(keys)
 
     def finish(self) -> VersionInfo:
         """Barrier the in-flight waves, commit every shard's release

@@ -39,7 +39,9 @@ the contract survives eviction.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import hashlib
 import os
 import re
@@ -50,7 +52,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
-from repro.obs import kerneltel
 from repro.obs.trace import StageTimer
 
 Timestamp = int
@@ -75,6 +76,11 @@ def _check_cancel(cancel: Callable[[], bool] | None) -> None:
     was cancelled or deadline-shed before paying for device work."""
     if cancel is not None and cancel():
         raise OperationCancelled("query cancelled between stages")
+
+
+def _no_leaf(_name: str):
+    """A leaf that is not opened: work off the ingest engine's thread."""
+    return contextlib.nullcontext()
 
 
 # the per-stage latency hook the serving layer aggregates into p50/p99
@@ -106,6 +112,22 @@ def _checked_cast(name: str, vals, dtype: np.dtype) -> np.ndarray:
             raise ValueError(
                 f"field {name}: magnitudes exceed the {dtype} range")
     return out
+
+
+def _diff_kinds(e0: np.ndarray, e1: np.ndarray,
+                changed: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """(rows, kinds) of one increment window from the rows alive at its
+    ends (``e0``, ``e1``) and the rows whose significant fields changed
+    inside it: new, deleted, and updated rows, in row order."""
+    new = e1 & ~e0
+    deleted = e0 & ~e1
+    updated = e1 & e0 & changed
+    sel = np.nonzero(new | deleted | updated)[0]
+    kind = np.zeros(len(sel), np.int8)
+    kind[new[sel]] = KIND_NEW
+    kind[updated[sel]] = KIND_UPDATED
+    kind[deleted[sel]] = KIND_DELETED
+    return sel, kind
 
 
 def _clamp_ts(t: Timestamp) -> int:
@@ -332,13 +354,24 @@ class _CellLog:
     def select_at(self, n_rows: int, t: Timestamp):
         """(vals_at_t (n_rows, W), found (n_rows,)) via the Pallas kernel.
         Only materializes on-disk segments at or below ``t``."""
+        return self.select_collect(n_rows, self.select_dispatch(n_rows, t))
+
+    def select_dispatch(self, n_rows: int, t: Timestamp):
+        """``select_at``'s device work, launched without a host sync: the
+        device (vals_at_t, found) pair, or None for an empty log."""
         vals, tss, ptr = self.csr(n_rows, through_ts=t)
         if len(tss) == 0:
-            return (np.zeros((n_rows, self.width), self.dtype),
-                    np.zeros(n_rows, bool))
-        out, found = kops.version_select(
+            return None
+        return kops.version_select(
             jnp.asarray(vals), jnp.asarray(tss.astype(np.int32)),
             jnp.asarray(ptr), _clamp_ts(t))
+
+    def select_collect(self, n_rows: int, handle):
+        """Copy a ``select_dispatch`` result to the host."""
+        if handle is None:
+            return (np.zeros((n_rows, self.width), self.dtype),
+                    np.zeros(n_rows, bool))
+        out, found = handle
         return np.asarray(out), np.asarray(found)
 
     def changed_counts(self, n_rows: int, t0: Timestamp, t1: Timestamp) -> np.ndarray:
@@ -525,7 +558,7 @@ class _SuperLog:
         qs = np.asarray([_clamp_ts(t) for t in ts_list], np.int32)
         out = np.zeros((len(qs), len(self.boundaries)), np.int32)
         if self.n_cells and len(qs):
-            q, c, b = len(qs), self.n_cells, len(self.boundaries)
+            q, b = len(qs), len(self.boundaries)
             # bucket the query and boundary axes like the cell axis (pow2,
             # outside jit): continuous ingest + varying wave widths then
             # revisit a handful of static shapes, so the scan AND the eager
@@ -537,24 +570,10 @@ class _SuperLog:
             bnd = self.boundaries
             if b_pad != b:  # zero-pad: boundary 0 reads count 0 below
                 bnd = np.concatenate([bnd, np.zeros(b_pad - b, np.int64)])
-            c_pad = kops.scan_bucket(c)
-            # traffic model: read the fused ts once (C*4), write the
-            # (Q, C) running cumsum, read+write the (Q, B) boundary
-            # columns; arithmetic: one compare + one add per (q, cell).
-            # logical uses the real shapes, padded the bucketed ones
-            with kerneltel.launch(
-                    "batched_select",
-                    nbytes=4 * (c + q * c + 2 * q * b),
-                    flops=2 * q * c,
-                    padded_nbytes=4 * (c_pad + q_pad * c_pad
-                                       + 2 * q_pad * b_pad)):
-                cum = kops.batched_masked_cumsum(self.ts, jnp.asarray(qs_in))
-                at = jnp.take(cum,
-                              jnp.asarray(np.maximum(bnd - 1, 0)),
-                              axis=1)
-                at = jnp.where(jnp.asarray(bnd == 0)[None, :],
-                               0, at)
-                out = np.asarray(at)[:q, :b]
+            cum = kops.batched_masked_cumsum(self.ts, jnp.asarray(qs_in))
+            at = jnp.take(cum, jnp.asarray(np.maximum(bnd - 1, 0)), axis=1)
+            at = jnp.where(jnp.asarray(bnd == 0)[None, :], 0, at)
+            out = np.asarray(at)[:q, :b]
         return out
 
     # -- per-field boundary math ----------------------------------------------
@@ -604,13 +623,28 @@ class _SuperLog:
         offs = np.cumsum([0] + lens)
         return [out[offs[i]: offs[i + 1]] for i in range(len(lens))]
 
-    def gather_many(self, name: str, cnts: "Sequence[np.ndarray]",
-                    sels: Sequence[np.ndarray]) -> list[np.ndarray]:
+    def gather_fields(self, names: Sequence[str],
+                      cnts: Callable[[str], "Sequence[np.ndarray]"],
+                      sels: Sequence[np.ndarray], trace: dict | None,
+                      zero: Sequence[np.ndarray] | None = None
+                      ) -> dict[str, list[np.ndarray]]:
         """Per-query row selections fused into ONE device gather per field:
-        cnts[q] the (N,) per-row counts and sels[q] the selected rows of
-        query q (dispatch + finalize in one step)."""
-        return self.gather_finalize(name, self.gather_dispatch(name, cnts,
-                                                               sels))
+        ``cnts(name)[q]`` the (N,) per-row counts and ``sels[q]`` the
+        selected rows of query q; rows where ``zero[q]`` is set come back
+        zeroed. Each field is two leaves of the gather stage:
+        ``gather.take`` (index math, device take or decode) and
+        ``gather.copy`` (the copy to the host, which waits for the take,
+        and the zeroing)."""
+        out = {}
+        for name in names:
+            with StageTimer(trace, "gather", "take"):
+                handle = self.gather_dispatch(name, cnts(name), sels)
+            with StageTimer(trace, "gather", "copy"):
+                parts = self.gather_finalize(name, handle)
+                for v, z in zip(parts, zero or ()):
+                    v[z] = 0
+            out[name] = parts
+        return out
 
 
 class _FieldColumn:
@@ -701,13 +735,35 @@ class ReleaseSession:
         the facade already value-cast the full chunk and fingerprinted it
         with ONE kernel launch per field, so the per-shard sub-applies
         skip the cast and slice the shared fingerprints instead of
-        launching ``n_shards`` small fingerprint kernels per field."""
+        launching ``n_shards`` small fingerprint kernels per field.
+
+        Called by the ingest engine's thread, the chunk is the leaves
+        ``ingest.route`` (validation, casts, row allocation),
+        ``ingest.fingerprint`` and ``ingest.append`` (cell appends, head
+        updates); the facade's sub-applies run on shard workers and open
+        none (see ``repro.obs.trace``)."""
         if self._finished:
             raise RuntimeError("release session already finished")
+        leaf = (_no_leaf if _precast
+                else functools.partial(StageTimer, None, "ingest"))
+        with leaf("route"):
+            keys, casted, rows, existed = self._route_chunk(keys, table,
+                                                           _precast)
+        if _fps is None:
+            with leaf("fingerprint"):
+                _fps = {name: kops.fingerprint_rows(vals)
+                        for name, vals in casted.items()}
+        with leaf("append"):
+            self._append(rows, existed, casted, _fps)
+        return len(keys)
+
+    def _route_chunk(self, keys, table, precast: bool):
+        """Validate and cast one chunk and allocate its rows; returns
+        (keys as bytes, cast value blocks, rows, rows that existed)."""
         st = self.store
         keys = [k.encode() if isinstance(k, str) else bytes(k) for k in keys]
         new_fields: dict[str, FieldSchema] = {}
-        if not _precast:
+        if not precast:
             for name in table:
                 if name not in st.fields:
                     fs = infer_field_schema(name, table[name])
@@ -715,7 +771,7 @@ class ReleaseSession:
                     new_fields[name] = fs
         casted: dict[str, np.ndarray] = {}
         for name, vals in table.items():
-            if _precast:
+            if precast:
                 casted[name] = vals
             else:
                 fs = new_fields.get(name) or st.fields[name].schema
@@ -736,13 +792,17 @@ class ReleaseSession:
         rows = st._rows_for_keys(keys, create=True)
         existed = np.zeros(len(keys), bool)
         existed[was_known] = st._exists_head[rows[was_known]]
+        return keys, casted, rows, existed
+
+    def _append(self, rows, existed, casted, fps) -> None:
+        """Append one routed chunk's changed cells and appearing rows."""
+        st = self.store
         is_new = ~existed
         chunk_updated = np.zeros(st.n_rows, bool)
         for name, vals in casted.items():
             col = st.fields[name]
             st._ensure_head(name)
-            fp = (_fps[name] if _fps is not None
-                  else kops.fingerprint_rows(vals))
+            fp = fps[name]
             same = (fp == col.head_fp[rows]).all(axis=1) & col.head_has[rows]
             changed = ~same
             if changed.any():
@@ -761,12 +821,11 @@ class ReleaseSession:
                                  np.ones((len(appearing), 1), np.int8))
             st._exists_head[appearing] = True
             self._appear_parts.append(appearing.tobytes())
-        self.n_entries += len(keys)
+        self.n_entries += len(rows)
         self._n_new += int(is_new.sum())
         self._n_upd += int((chunk_updated[rows] & existed).sum())
         self._rows_parts.append(rows)
         st._invalidate_log()  # mid-session queries must not reuse caches
-        return len(keys)
 
     def finish(self) -> VersionInfo:
         """Commit the release: tombstone scan (full releases), version
@@ -1212,8 +1271,11 @@ class VersionedStore:
             store is untouched — queries never mutate).
           trace: optional dict accumulating per-stage wall seconds under
             ``"scan"`` (superlog build + batched masked-cumsum + exists
-            resolution), ``"gather"`` (fused value gathers) and
-            ``"materialize"`` (view assembly). Additive across calls.
+            resolution), ``"gather"`` (fused value gathers and their copy
+            to the host) and ``"materialize"`` (view assembly), and under
+            each stage's leaves (``"scan.build"``, ``"scan.select"``,
+            ``"scan.exists"``, ``"gather.take"``, ``"gather.copy"``; see
+            ``repro.obs.trace``). Additive across calls.
 
         Returns:
           list[VersionView] aligned with ``ts_list``.
@@ -1232,19 +1294,15 @@ class VersionedStore:
             v = self._get_version_cold(uniq[0], fields, key_filter,
                                        include_deleted, trace=trace)
             return [v] * len(ts_list)
-        with _StageTimer(trace, "scan"):
-            sl = self.superlog()
-            bcum = sl.boundary_cums(uniq)
-            alive, ever = sl.exists_matrix(bcum)
+        sl, bcum, (alive, ever) = self._scan(uniq, trace)
         if include_deleted:
             alive = ever
         _check_cancel(cancel)
-        with _StageTimer(trace, "gather"):
-            field_cnt = {name: sl.counts(name, bcum) for name in fields}
+        with _StageTimer(trace, "gather", "take"):
             sels = [self._filter_sel(np.nonzero(alive[qi])[0], key_filter)
                     for qi in range(len(uniq))]
-            vals = {name: sl.gather_many(name, field_cnt[name], sels)
-                    for name in fields}
+        vals = sl.gather_fields(fields, lambda name: sl.counts(name, bcum),
+                                sels, trace)
         _check_cancel(cancel)
         with _StageTimer(trace, "materialize"):
             by_t = {}
@@ -1254,6 +1312,20 @@ class VersionedStore:
                     row_idx=sel.astype(np.int32),
                     values={name: vals[name][qi] for name in fields})
             return [by_t[t] for t in ts_list]
+
+    def _scan(self, uniq: Sequence[Timestamp], trace: dict | None):
+        """The scan stage over the fused superlog, as its three leaves:
+        ``scan.build`` (superlog build or refresh, the ts upload),
+        ``scan.select`` (the batched boundary scan and its copy to the
+        host) and ``scan.exists``. Returns (superlog, boundary cumsums,
+        (alive, ever))."""
+        with _StageTimer(trace, "scan", "build"):
+            sl = self.superlog()
+            sl.ts  # the fused ts upload happens on first use
+        with _StageTimer(trace, "scan", "select"):
+            bcum = sl.boundary_cums(uniq)
+        with _StageTimer(trace, "scan", "exists"):
+            return sl, bcum, sl.exists_matrix(bcum)
 
     def get_version(self, t: Timestamp, *, fields: Sequence[str] | None = None,
                     key_filter: str | Callable[[bytes], bool] | None = None,
@@ -1269,15 +1341,18 @@ class VersionedStore:
         # "ever existed" = any EXISTS cell with ts <= t; the found flag
         # matches _SuperLog.exists_matrix exactly (a windowed
         # changed_counts(-1, t) would drop cells at negative ts)
-        with _StageTimer(trace, "scan"):
+        with _StageTimer(trace, "scan", "select"):
             vals, found = self.exists_log.select_at(self.n_rows, t)
+        with _StageTimer(trace, "scan", "exists"):
             alive = found if include_deleted else (vals[:, 0] > 0) & found
             sel = self._filter_sel(np.nonzero(alive)[0], key_filter)
-        with _StageTimer(trace, "gather"):
-            values = {}
-            for name in fields:
-                vals, _found = self.fields[name].log.select_at(self.n_rows, t)
-                values[name] = vals[sel]
+        values = {}
+        for name in fields:
+            log = self.fields[name].log
+            with _StageTimer(trace, "gather", "take"):
+                handle = log.select_dispatch(self.n_rows, t)
+            with _StageTimer(trace, "gather", "copy"):
+                values[name] = log.select_collect(self.n_rows, handle)[0][sel]
         with _StageTimer(trace, "materialize"):
             return VersionView(ts=t, keys=[self.row_keys[r] for r in sel],
                                row_idx=sel.astype(np.int32), values=values)
@@ -1285,7 +1360,8 @@ class VersionedStore:
     # -- get_increment / get_increments (§III.C) -------------------------------
     def get_increments(self, pairs: Sequence[tuple[Timestamp, Timestamp]], *,
                        significant_fields: Sequence[str] | None = None,
-                       fields: Sequence[str] | None = None) -> list[Increment]:
+                       fields: Sequence[str] | None = None,
+                       trace: dict | None = None) -> list[Increment]:
         """Entries whose significant fields changed in (t0, t1], for many
         (t0, t1) windows at once: one batched scan over the unique window
         endpoints serves every pair. Duplicate windows are computed once
@@ -1301,6 +1377,9 @@ class VersionedStore:
             (default: all fields).
           fields: fields materialized into ``values`` (default: all;
             pass ``[]`` for keys/kinds only).
+          trace: optional dict accumulating per-stage wall seconds, as in
+            ``get_versions``, with one more stage: ``"diff"`` (the host
+            masks of changed, new and deleted rows, and their kinds).
 
         Returns:
           list[Increment] aligned with ``pairs`` (values at t1, zeroed
@@ -1318,46 +1397,36 @@ class VersionedStore:
         upairs = list(dict.fromkeys(pairs))
         if len(upairs) == 1 and not self._superlog_fresh():
             inc = self._get_increment_cold(*upairs[0], sig=sig,
-                                           out_fields=out_fields)
+                                           out_fields=out_fields,
+                                           trace=trace)
             return [inc] * len(pairs)
         uniq = list(dict.fromkeys(t for p in upairs for t in p))
         q_of = {t: i for i, t in enumerate(uniq)}
-        sl = self.superlog()
-        bcum = sl.boundary_cums(uniq)
-        exists, _ever = sl.exists_matrix(bcum)
-        cnt = {name: sl.counts(name, bcum)
-               for name in dict.fromkeys(sig + out_fields)}
-        sels, kinds = [], []
-        for t0, t1 in upairs:
-            i0, i1 = q_of[t0], q_of[t1]
-            changed = np.zeros(self.n_rows, bool)
-            for name in sig:
-                changed |= (cnt[name][i1] - cnt[name][i0]) > 0
-            e0, e1 = exists[i0], exists[i1]
-            new = e1 & ~e0
-            deleted = e0 & ~e1
-            updated = e1 & e0 & changed
-            sel = np.nonzero(new | deleted | updated)[0]
-            kind = np.zeros(len(sel), np.int8)
-            kind[new[sel]] = KIND_NEW
-            kind[updated[sel]] = KIND_UPDATED
-            kind[deleted[sel]] = KIND_DELETED
-            sels.append(sel)
-            kinds.append(kind)
-        vals = {name: sl.gather_many(name, [cnt[name][q_of[t1]]
-                                            for _, t1 in upairs], sels)
-                for name in out_fields}
-        by_pair = {}
-        for qi, ((t0, t1), sel, kind) in enumerate(zip(upairs, sels, kinds)):
-            values = {}
-            for name in out_fields:
-                v = vals[name][qi]
-                v[kind == KIND_DELETED] = 0
-                values[name] = v
-            by_pair[(t0, t1)] = Increment(
-                t0=t0, t1=t1, keys=[self.row_keys[r] for r in sel],
-                row_idx=sel.astype(np.int32), kind=kind, values=values)
-        return [by_pair[p] for p in pairs]
+        sl, bcum, (exists, _ever) = self._scan(uniq, trace)
+        with _StageTimer(trace, "diff"):
+            cnt = {name: sl.counts(name, bcum)
+                   for name in dict.fromkeys(sig + out_fields)}
+            sels, kinds = [], []
+            for t0, t1 in upairs:
+                i0, i1 = q_of[t0], q_of[t1]
+                changed = np.zeros(self.n_rows, bool)
+                for name in sig:
+                    changed |= (cnt[name][i1] - cnt[name][i0]) > 0
+                sel, kind = _diff_kinds(exists[i0], exists[i1], changed)
+                sels.append(sel)
+                kinds.append(kind)
+        vals = sl.gather_fields(
+            out_fields, lambda name: [cnt[name][q_of[t1]] for _, t1 in upairs],
+            sels, trace, zero=[kind == KIND_DELETED for kind in kinds])
+        with _StageTimer(trace, "materialize"):
+            by_pair = {}
+            for qi, ((t0, t1), sel, kind) in enumerate(zip(upairs, sels,
+                                                          kinds)):
+                by_pair[(t0, t1)] = Increment(
+                    t0=t0, t1=t1, keys=[self.row_keys[r] for r in sel],
+                    row_idx=sel.astype(np.int32), kind=kind,
+                    values={name: vals[name][qi] for name in out_fields})
+            return [by_pair[p] for p in pairs]
 
     def get_increment(self, t0: Timestamp, t1: Timestamp, *,
                       significant_fields: Sequence[str] | None = None,
@@ -1367,32 +1436,33 @@ class VersionedStore:
                                    fields=fields)[0]
 
     def _get_increment_cold(self, t0: Timestamp, t1: Timestamp, *,
-                            sig: list[str], out_fields: list[str]) -> Increment:
+                            sig: list[str], out_fields: list[str],
+                            trace: dict | None = None) -> Increment:
         """Single-window increment over the involved fields' own CSR logs
         (no fused-superlog build)."""
-        changed = np.zeros(self.n_rows, bool)
-        for name in sig:
-            changed |= self.fields[name].log.changed_counts(
-                self.n_rows, t0, t1) > 0
-        e0 = self.exists_at(t0)
-        e1 = self.exists_at(t1)
-        new = e1 & ~e0
-        deleted = e0 & ~e1
-        updated = e1 & e0 & changed
-        sel = np.nonzero(new | deleted | updated)[0]
-        kind = np.zeros(len(sel), np.int8)
-        kind[new[sel]] = KIND_NEW
-        kind[updated[sel]] = KIND_UPDATED
-        kind[deleted[sel]] = KIND_DELETED
+        with _StageTimer(trace, "scan", "select"):
+            changed = np.zeros(self.n_rows, bool)
+            for name in sig:
+                changed |= self.fields[name].log.changed_counts(
+                    self.n_rows, t0, t1) > 0
+            e0 = self.exists_at(t0)
+            e1 = self.exists_at(t1)
+        with _StageTimer(trace, "diff"):
+            sel, kind = _diff_kinds(e0, e1, changed)
         values = {}
         for name in out_fields:
-            vals, _ = self.fields[name].log.select_at(self.n_rows, t1)
-            v = vals[sel]
-            v[kind == KIND_DELETED] = 0
+            log = self.fields[name].log
+            with _StageTimer(trace, "gather", "take"):
+                handle = log.select_dispatch(self.n_rows, t1)
+            with _StageTimer(trace, "gather", "copy"):
+                v = log.select_collect(self.n_rows, handle)[0][sel]
+                v[kind == KIND_DELETED] = 0
             values[name] = v
-        return Increment(t0=t0, t1=t1, keys=[self.row_keys[r] for r in sel],
-                         row_idx=sel.astype(np.int32), kind=kind,
-                         values=values)
+        with _StageTimer(trace, "materialize"):
+            return Increment(t0=t0, t1=t1,
+                             keys=[self.row_keys[r] for r in sel],
+                             row_idx=sel.astype(np.int32), kind=kind,
+                             values=values)
 
     # -- compaction (production housekeeping; paper §III.E leaves retention
     # to "a cron job" — at fleet scale the cell log needs real compaction) --

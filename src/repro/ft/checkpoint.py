@@ -37,11 +37,13 @@ JOURNAL_NAME = "JOURNAL.json"
 
 
 def _fsync_write(path: str, data: bytes) -> None:
-    """Write ``data`` atomically (tmp + fsync + rename + dir fsync)."""
-    from repro.core.segments import _fsync_dir
+    """Write ``data`` atomically (tmp + fsync + rename + dir fsync),
+    counted in ``storage.bytes_written``."""
+    from repro.core.segments import _fsync_dir, count_written
     tmp = path + ".tmp"
     with open(tmp, "wb") as f:
         f.write(data)
+        count_written(len(data))
         f.flush()
         os.fsync(f.fileno())
     os.replace(tmp, path)

@@ -5,7 +5,7 @@ one base cell and splices the surviving tail back in (row, ts) order. The
 math used to live entirely in host numpy; the heavy parts — the horizon
 keep-mask over the cell timestamps and the (C, W) value-byte rewrite into
 the new CSR order — now run on device through the shared launch helper
-(kernels/launch.py), under the ``compact_rewrite`` telemetry name:
+(kernels/launch.py), under the ``compact_rewrite`` tile name:
 
   * a row-tiled Pallas kernel computes the ``ts > horizon`` keep mask
     (bandwidth-bound, same launch family as shard_route);
@@ -80,23 +80,13 @@ def compact_rewrite(vals, tss, ptr, base_vals, base_found, before_ts,
       paths (pinned by the equivalence tests).
     """
     c = len(tss)
-    w = vals.shape[1] if vals.ndim == 2 else 1
-    use_ref = (interpret is None and interpret_default()) \
-        or vals.dtype.itemsize == 8 or c == 0
-    # traffic model: stream the (C,) ts for the mask (read + int32 mask
-    # write) and move every value byte once on each side of the gather;
-    # arithmetic: one compare per cell. padded adds the mask tile slack.
-    t = launch.tile_for("compact_rewrite", n=c)
-    c_pad = launch.round_up_tile(c, t)
-    nb = 8 * c + 2 * (vals.nbytes + base_vals.nbytes)
-    with launch.measured("compact_rewrite", nbytes=nb, flops=c,
-                         padded_nbytes=nb + 8 * (c_pad - c)):
-        if use_ref:
-            return ref_compact_rewrite(vals, tss, ptr, base_vals,
-                                       base_found, before_ts, n_rows)
-        return _device_rewrite(vals, tss, ptr, base_vals, base_found,
-                               before_ts, n_rows,
-                               interpret=bool(interpret), tile=t)
+    if ((interpret is None and interpret_default())
+            or vals.dtype.itemsize == 8 or c == 0):
+        return ref_compact_rewrite(vals, tss, ptr, base_vals, base_found,
+                                   before_ts, n_rows)
+    return _device_rewrite(vals, tss, ptr, base_vals, base_found,
+                           before_ts, n_rows, interpret=bool(interpret),
+                           tile=launch.tile_for("compact_rewrite", n=c))
 
 
 def _device_rewrite(vals, tss, ptr, base_vals, base_found, before_ts,

@@ -311,17 +311,7 @@ def chain_pack(vals: np.ndarray, rows: np.ndarray) -> tuple[np.ndarray, dict]:
     """
     if len(vals) == 0:
         return vals.copy(), {"mode": "raw", "dtype": vals.dtype.name}
-    # traffic model: read new + predecessor cells, write the delta;
-    # arithmetic: one sub/xor per element (the narrowing stat rides along).
-    # logical = the cells themselves; padded adds the pow2 bucket slack the
-    # kernel actually streams (8-byte host path: no padding happens)
-    n = len(vals)
-    n_pad = n if vals.dtype.itemsize == 8 else _codec_bucket(n)
-    with launch.measured("delta_codec", nbytes=3 * vals.nbytes,
-                         flops=vals.size,
-                         padded_nbytes=3 * n_pad * vals.itemsize
-                         * (vals.size // n)):
-        return _chain_pack_timed(vals, rows)
+    return _chain_pack(vals, rows)
 
 
 def _codec_bucket(n: int) -> int:
@@ -330,7 +320,7 @@ def _codec_bucket(n: int) -> int:
     return launch.pow2_bucket(n, floor=launch.tile_for("delta_codec"))
 
 
-def _chain_pack_timed(vals: np.ndarray, rows: np.ndarray) -> tuple[np.ndarray, dict]:
+def _chain_pack(vals: np.ndarray, rows: np.ndarray) -> tuple[np.ndarray, dict]:
     first = _chain_heads(rows)
     prev = np.roll(vals, 1, axis=0)
     prev[first] = 0  # chain heads pack against zero (stored raw)
@@ -393,15 +383,10 @@ def chain_unpack(packed: np.ndarray, rows: np.ndarray, meta: dict,
     """
     if meta["mode"] == "raw" or len(packed) == 0:
         return packed.astype(out_dtype)
-    # traffic model mirrors chain_pack's: read delta + predecessor,
-    # write the reconstruction; one add/xor per element (the host depth
-    # loop moves logical bytes only — no pad slack on the unpack side)
-    with launch.measured("delta_codec", nbytes=3 * packed.nbytes,
-                         flops=packed.size):
-        return _chain_unpack_timed(packed, rows, meta, out_dtype)
+    return _chain_unpack(packed, rows, meta, out_dtype)
 
 
-def _chain_unpack_timed(packed: np.ndarray, rows: np.ndarray, meta: dict,
+def _chain_unpack(packed: np.ndarray, rows: np.ndarray, meta: dict,
                         out_dtype: np.dtype) -> np.ndarray:
     stored = np.dtype(meta["dtype"])
     delta = packed.astype(stored) if "narrow" in meta else packed

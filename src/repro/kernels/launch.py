@@ -1,11 +1,10 @@
-"""Unified kernel-launch plumbing: tiles, buckets, autotune, telemetry.
+"""Unified kernel-launch plumbing: tiles, buckets, autotune.
 
 Every kernel in the family (``batched_select``, ``shard_route``,
 ``delta_codec``, ``compact_rewrite``) used to carry its own copy of the
 same host-side launch logic — pad the leading axis to a hardcoded tile
-multiple, build the grid/BlockSpec boilerplate, pick interpret mode, and
-wrap the host-sync site in ``kerneltel``. This module is that plumbing,
-written once:
+multiple, build the grid/BlockSpec boilerplate, pick interpret mode. This
+module is that plumbing, written once:
 
   * **Tile resolution** (:func:`tile_for`): ``GESTORE_TILE_<KERNEL>`` env
     override > autotuned winner from the on-disk cache > built-in default.
@@ -32,10 +31,6 @@ written once:
   * **Row-tiled pallas_call builder** (:func:`tiled_rows`): the shared
     1-D-grid launch shape (pad rows to a tile multiple, per-tile row
     blocks, slice back to the logical row count).
-  * **Telemetry** (:func:`measured`): the ``kerneltel.launch`` wrap used
-    by every host-facing call site, carrying *both* the logical traffic
-    model and the padded bytes that actually move (bucket slack must not
-    skew roofline fractions — see obs/kerneltel.py).
 
 On the CPU backend the kernels dispatch to their jnp reference oracles, so
 tile choice is a no-op there; the sweep still records a winner (cheap) to
@@ -278,17 +273,6 @@ def tiled_rows(body, inputs, outs, *, tile: int, interpret: bool):
                          out_specs=out_specs, out_shape=out_shape,
                          interpret=interpret)(*inputs)
     return tuple(r[:n] for r in res)
-
-
-# -- telemetry ----------------------------------------------------------------
-
-def measured(kernel: str, *, nbytes: float, flops: float,
-             padded_nbytes: float | None = None):
-    """The kernel family's ``kerneltel.launch`` wrap: logical traffic model
-    plus the padded bytes that actually cross HBM (bucket/tile slack)."""
-    from repro.obs import kerneltel
-    return kerneltel.launch(kernel, nbytes=nbytes, flops=flops,
-                            padded_nbytes=padded_nbytes)
 
 
 # -- persistent compile cache -------------------------------------------------

@@ -105,16 +105,8 @@ def route_keys(keys: Sequence[bytes], n_shards: int) -> np.ndarray:
     if n_shards == 1:
         return np.zeros(len(keys), np.int32)
     lanes, lens = key_lanes(keys)
-    n, w = lanes.shape
-    # traffic model: read (N, W) lanes + (N,) lengths, write (N,) ids;
-    # arithmetic: ~8 integer ops per lane in the xor-rotate fold + the
-    # 5-op finalizer per key; padded counts the tile-multiple row slack
-    n_pad = launch.round_up_tile(n, launch.tile_for("shard_route", n=n))
-    with launch.measured("shard_route", nbytes=4 * (n * w + 2 * n),
-                         flops=n * (8 * w + 5),
-                         padded_nbytes=4 * (n_pad * w + 2 * n_pad)):
-        return np.asarray(shard_route(jnp.asarray(lanes), jnp.asarray(lens),
-                                      int(n_shards)))
+    return np.asarray(shard_route(jnp.asarray(lanes), jnp.asarray(lens),
+                                  int(n_shards)))
 
 
 def merge_shard_rows(parts: Sequence[np.ndarray]) -> tuple[np.ndarray, np.ndarray]:

@@ -161,38 +161,6 @@ class Roofline:
         }
 
 
-def kernel_roofline(flops: float, nbytes: float, wall_s: float, *,
-                    device_kind: str | None = None) -> dict:
-    """Single-kernel roofline terms from host-side launch accounting.
-
-    ``flops``/``nbytes`` are the launch path's analytic estimates (see
-    ``obs/kerneltel.py`` per-site models), ``wall_s`` the measured
-    launch-to-host-sync wall. ``roofline_fraction`` is the fraction of
-    the roofline-implied minimum time actually achieved —
-    ``max(t_compute, t_memory) / wall`` against the :data:`PEAKS` entry
-    of ``device_kind`` (default: the first JAX device's). For a device
-    with no entry every term is None: there is no peak to divide by.
-    """
-    if device_kind is None:
-        import jax
-        device_kind = jax.devices()[0].device_kind
-    peaks = PEAKS.get(device_kind)
-    if peaks is None:
-        return {"device_kind": device_kind, "t_compute_s": None,
-                "t_memory_s": None, "dominant": None,
-                "roofline_fraction": None}
-    t_compute = flops / peaks.flops
-    t_memory = nbytes / peaks.hbm_bw
-    t_min = max(t_compute, t_memory)
-    return {
-        "device_kind": device_kind,
-        "t_compute_s": t_compute,
-        "t_memory_s": t_memory,
-        "dominant": "compute" if t_compute >= t_memory else "memory",
-        "roofline_fraction": (t_min / wall_s) if wall_s > 0 else 0.0,
-    }
-
-
 def model_flops(cfg, shape) -> float:
     """Analytic MODEL_FLOPS: 6*N_active*D for training, 2*N_active*D for a
     forward-only step (+ attention term for long contexts)."""

@@ -7,20 +7,20 @@ One telemetry spine for the whole serving stack (see ARCHITECTURE.md
     in ``MetricsRegistry`` instances; ``REGISTRY`` is the process-wide
     default, with JSON and Prometheus text exposition.
   * ``trace`` — request/wave trace IDs (minted at ``FrontDoor.submit``)
-    and thread-local ``span()`` contexts; ``StageTimer`` carries the old
-    per-stage trace-dict contract and feeds spans + registry.
+    and thread-local ``span()`` contexts; ``StageTimer`` times one leaf
+    of a coarse stage into the per-stage trace dict, spans and registry,
+    and holds a ``jax.profiler.TraceAnnotation`` (``gestore.<stage>.<leaf>``)
+    so a profiler trace shows it on the device's clock.
   * ``recorder`` — ``RECORDER``, a bounded ring of structured events
     (rejections, failures, pool churn, spans) dumping to JSON on demand
     or on unhandled failure (``GESTORE_FLIGHT_DUMP``).
-  * ``kerneltel`` — per-kernel launch wall/bytes/FLOPs feeding
-    ``launch/roofline.py`` fractions (``KERNELS``).
   * ``log`` — the leveled, env-configurable (``GESTORE_LOG``) structured
     logger; quiet by default, the only sanctioned output path for
     library code (ruff bans ``print`` under ``src/``).
 
-``kerneltel`` is imported lazily by its call sites (it pulls in
-``launch.roofline``); importing ``repro.obs`` itself stays stdlib+numpy
-light so ``core``/``serve`` can depend on it unconditionally.
+Importing ``repro.obs`` stays stdlib+numpy light (``jax.profiler`` is
+imported on the first ``StageTimer``), so ``core``/``serve`` can depend
+on it unconditionally.
 """
 from .log import configure as configure_logging, get_logger
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
@@ -38,10 +38,9 @@ __all__ = [
 
 
 def snapshot_all() -> dict:
-    """One combined observability snapshot: global registry metrics,
-    per-kernel roofline telemetry, and the flight-recorder dump — the
-    payload ``benchmarks/table10_observability.py`` writes to
+    """One combined observability snapshot: global registry metrics and
+    the flight-recorder dump — the payload
+    ``benchmarks/table10_observability.py`` writes to
     ``BENCH_metrics.json``."""
-    from .kerneltel import KERNELS
-    return {"metrics": REGISTRY.snapshot(), "kernels": KERNELS.snapshot(),
+    return {"metrics": REGISTRY.snapshot(),
             "flight_recorder": RECORDER.dump()}

@@ -2,10 +2,10 @@
 
 One telemetry spine for the whole stack (the tentpole of the
 observability layer): the serving front door, the tiered store pool, the
-kernel launch paths, and the segment I/O layer all publish into
-``MetricsRegistry`` instances instead of growing private ad-hoc stat
-dicts. The module-level ``REGISTRY`` is the process-wide default —
-kernel telemetry and pool churn land there — while components whose
+store's stages, the ingest engine, and the segment I/O layer all publish
+into ``MetricsRegistry`` instances instead of growing private ad-hoc stat
+dicts. The module-level ``REGISTRY`` is the process-wide default — stage
+timings, pool churn and storage bytes land there — while components whose
 stats must stay instance-scoped (e.g. every ``FrontDoor`` owns its
 latency histograms, so two doors in one process never alias) construct
 their own registry from the same primitives.
@@ -187,6 +187,6 @@ class MetricsRegistry:
             self._metrics.clear()
 
 
-#: the process-wide default registry: kernel telemetry, pool churn, and
-#: stage timings publish here; scrape with ``REGISTRY.to_prometheus()``.
+#: the process-wide default registry: pool churn, stage timings and
+#: storage bytes publish here; scrape with ``REGISTRY.to_prometheus()``.
 REGISTRY = MetricsRegistry()

@@ -7,12 +7,21 @@ the store scan/gather/materialize stages, ``core/segments.py`` reads —
 can attach its timings and failure events to the active trace without
 any plumbing through intermediate signatures.
 
-``StageTimer`` is the migration of the old ``core.store._StageTimer``:
-it keeps the additive ``trace[stage] += seconds`` contract the serving
-layer aggregates (``FrontDoor.stats()`` semantics are unchanged), and
-additionally folds each stage's seconds into the enclosing span (where
-they appear in the flight-recorder event) and into the process-wide
-registry histogram ``stage.<name>``.
+``StageTimer`` times one *leaf* of a coarse stage, e.g.
+``StageTimer(trace, "gather", "copy")``: it adds its seconds to
+``trace["gather"]`` and ``trace["gather.copy"]`` (the additive contract
+``FrontDoor.stats()`` aggregates), to the enclosing span, and to the
+process-wide registry histogram ``stage.gather.copy``. While it is open
+it holds a ``jax.profiler.TraceAnnotation("gestore.gather.copy")``, so a
+profiler trace shows the leaf on the same clock as the device's work. A
+coarse stage is the sum of its leaves, and the leaves tile its body; a
+leaf never encloses another leaf, and only the thread that drives the
+device opens them (a trace names each idle gap after the host event
+that overlaps it most, and an enclosing annotation would take them all).
+Work handed to another thread shows on the driving thread as the leaf
+that waits for it; such threads stay on the host clock (spans, registry
+histograms). ``jax.profiler`` is imported on the first leaf, so importing
+this package does not import JAX; without JAX the annotation is a no-op.
 
 Span lifecycle: ``span(name, ...)`` pushes onto the calling thread's
 stack (nesting gives ``parent`` links), and on exit records one
@@ -125,29 +134,70 @@ class span:
         return False
 
 
-class StageTimer:
-    """Accumulate wall seconds into ``trace[stage]`` (no-op when trace is
-    None) — the per-stage latency hook the serving layer aggregates into
-    p50/p99 histograms. Additive: one trace dict can span a whole wave.
-    Each exit also feeds the enclosing span (if any) and the process-wide
-    ``stage.<name>`` histogram."""
+class _NoAnnotation:
+    """Stand-in for ``TraceAnnotation`` where JAX is not installed."""
 
-    __slots__ = ("_trace", "_stage", "_t0")
-
-    def __init__(self, trace: dict | None, stage: str):
-        self._trace, self._stage = trace, stage
+    def __init__(self, name: str):
+        pass
 
     def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_annotation = None
+
+
+def _trace_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` (no keyword
+    arguments, so the trace event's name is exactly ``name``)."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = _NoAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(name)
+
+
+class StageTimer:
+    """Time one leaf of a coarse stage (see the module docstring).
+
+    ``StageTimer(trace, stage)`` is a leaf with no sub-name: its key is
+    ``stage``. ``StageTimer(trace, stage, leaf)`` adds its wall seconds to
+    ``trace[stage]`` and to ``trace["<stage>.<leaf>"]`` (no-op when trace
+    is None; additive, so one trace dict can span a whole wave), to both
+    keys of the enclosing span (if any), and to the process-wide
+    ``stage.<key>`` histogram; while open it holds the profiler
+    annotation ``gestore.<key>``."""
+
+    __slots__ = ("_trace", "_stage", "_key", "_ann", "_t0")
+
+    def __init__(self, trace: dict | None, stage: str,
+                 leaf: str | None = None):
+        self._trace, self._stage = trace, stage
+        self._key = stage if leaf is None else f"{stage}.{leaf}"
+
+    def __enter__(self):
+        self._ann = _trace_annotation("gestore." + self._key)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        keys = ((self._stage,) if self._key == self._stage
+                else (self._stage, self._key))
         if self._trace is not None:
-            self._trace[self._stage] = (self._trace.get(self._stage, 0.0)
-                                        + dt)
+            for k in keys:
+                self._trace[k] = self._trace.get(k, 0.0) + dt
         s = current_span()
         if s is not None:
-            s.add_stage(self._stage, dt)
-        REGISTRY.histogram(f"stage.{self._stage}").record(dt)
+            for k in keys:
+                s.add_stage(k, dt)
+        REGISTRY.histogram(f"stage.{self._key}").record(dt)
         return False

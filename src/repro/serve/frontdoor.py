@@ -63,8 +63,9 @@ cancelled or shed aborts between stages instead of paying for device work
 runs inside a ``span()`` carrying that id, so stage timings and failure
 events all the way down to segment I/O land in the flight recorder
 under the request's trace. Per-stage wall times (queue, batch-form,
-scan, gather, materialize, exec, total) aggregate into bounded
-``repro.obs`` histograms — owned by a per-door ``MetricsRegistry`` so
+scan, gather, materialize, exec, total, and every other key a wave's
+trace dict carries, such as the leaf ``gather.copy``) aggregate into
+bounded ``repro.obs`` histograms — owned by a per-door ``MetricsRegistry`` so
 two doors in one process never alias — surfaced as p50/p99 by
 ``stats()``, which ``benchmarks/table9_serving.py`` writes into
 ``BENCH_results.json``. Rejections (queue-full, pressure, deadline) are
@@ -88,7 +89,7 @@ from typing import Callable, Mapping, Sequence
 
 from repro.obs import RECORDER
 from repro.obs.metrics import Histogram, MetricsRegistry
-from repro.obs.trace import new_trace_id, span
+from repro.obs.trace import StageTimer, new_trace_id, span
 
 from .gestore_service import GeStoreService, VersionRequest
 
@@ -433,7 +434,7 @@ class FrontDoor:
         """Form and execute one wave; False when every queue is idle."""
         with self._dispatch_lock:
             t0 = time.perf_counter()
-            with self._lock:
+            with StageTimer(None, "frontdoor", "form"), self._lock:
                 wave = self._form_wave_locked()
             if wave is None:
                 return False
@@ -485,11 +486,20 @@ class FrontDoor:
         self.service.enforce_pool()   # mutations grow stores: honor budget
         self._finish([t], {}, time.perf_counter() - t0)
 
+    def _hist(self, stage: str) -> Histogram:
+        """The ``latency.<stage>`` histogram, created on first use (caller
+        holds the lock)."""
+        h = self._hists.get(stage)
+        if h is None:
+            h = self._hists[stage] = self.metrics.histogram(
+                f"latency.{stage}", self.config.hist_cap)
+        return h
+
     def _finish(self, wave: list[Ticket], trace: dict, exec_s: float) -> None:
-        now = self.config.clock()
-        with self._lock:
+        with StageTimer(None, "frontdoor", "finish"), self._lock:
+            now = self.config.clock()
             for stage, secs in trace.items():
-                self._hists[stage].record(secs)
+                self._hist(stage).record(secs)
             self._hists["exec"].record(exec_s)
             for t in wave:
                 total = now - t.t_submit

@@ -35,7 +35,7 @@ from concurrent.futures import Future
 from typing import Iterator, Mapping, Sequence
 
 from repro.core.store import VersionedStore, VersionView
-from repro.obs import RECORDER, REGISTRY
+from repro.obs import RECORDER, REGISTRY, StageTimer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -461,21 +461,28 @@ class GeStoreService:
     def _serve(self, pending: list[tuple[VersionRequest, Future]], *,
                cancel=None, trace: dict | None = None,
                enforce_pool: bool = True) -> int:
-        groups: dict[tuple, list[tuple[VersionRequest, Future]]] = {}
-        for req, fut in pending:
-            groups.setdefault(req.group_key(), []).append((req, fut))
+        """Serve ``pending``; the service's own work around each store call
+        is the leaves ``serve.plan`` (grouping, plan-cache lookups),
+        ``serve.deliver`` (freezing views, resolving futures) and
+        ``serve.enforce`` (the tiered budget), none enclosing the store's
+        own stages."""
+        with StageTimer(trace, "serve", "plan"):
+            groups: dict[tuple, list[tuple[VersionRequest, Future]]] = {}
+            for req, fut in pending:
+                groups.setdefault(req.group_key(), []).append((req, fut))
         for (store_name, fields, key_filter, include_deleted), items in groups.items():
             try:
-                store = self._stores[store_name]
-                plan = self._plan(store_name)
-                todo = []  # deduped uncached plan keys, insertion-ordered
-                for req, _ in items:
-                    pk = req.plan_key()
-                    if pk in plan or pk in todo:  # in-flight dup = a hit too
-                        self.stats["plan_hits"] += 1
-                    else:
-                        todo.append(pk)
-                        self.stats["plan_misses"] += 1
+                with StageTimer(trace, "serve", "plan"):
+                    store = self._stores[store_name]
+                    plan = self._plan(store_name)
+                    todo = []  # deduped uncached plan keys, insertion-ordered
+                    for req, _ in items:
+                        pk = req.plan_key()
+                        if pk in plan or pk in todo:  # in-flight dup = a hit
+                            self.stats["plan_hits"] += 1
+                        else:
+                            todo.append(pk)
+                            self.stats["plan_misses"] += 1
                 for chunk in (todo[i:i + self.max_batch]
                               for i in range(0, len(todo), self.max_batch)):
                     views = store.get_versions(
@@ -484,24 +491,26 @@ class GeStoreService:
                         key_filter=key_filter,
                         include_deleted=include_deleted,
                         cancel=cancel, trace=trace)
-                    self.stats["batches"] += 1
-                    for view in views:
-                        # memoized views are shared across clients: freeze
-                        # them so in-place edits fail loudly instead of
-                        # corrupting every later cache hit
-                        for arr in view.values.values():
-                            arr.setflags(write=False)
-                        view.row_idx.setflags(write=False)
-                    plan.update(zip(chunk, views))
-                for req, fut in items:
-                    pk = req.plan_key()
-                    plan.move_to_end(pk)
-                    view = plan[pk]
-                    if fut.set_running_or_notify_cancel():  # skip cancelled
-                        fut.set_result(view)
-                # bound memory within one long-lived epoch too
-                while len(plan) > self.max_views_per_plan:
-                    plan.popitem(last=False)
+                    with StageTimer(trace, "serve", "deliver"):
+                        self.stats["batches"] += 1
+                        for view in views:
+                            # memoized views are shared across clients:
+                            # freeze them so in-place edits fail loudly
+                            # instead of corrupting every later cache hit
+                            for arr in view.values.values():
+                                arr.setflags(write=False)
+                            view.row_idx.setflags(write=False)
+                        plan.update(zip(chunk, views))
+                with StageTimer(trace, "serve", "deliver"):
+                    for req, fut in items:
+                        pk = req.plan_key()
+                        plan.move_to_end(pk)
+                        view = plan[pk]
+                        if fut.set_running_or_notify_cancel():  # skip cancelled
+                            fut.set_result(view)
+                    # bound memory within one long-lived epoch too
+                    while len(plan) > self.max_views_per_plan:
+                        plan.popitem(last=False)
             except Exception as e:
                 REGISTRY.counter("service.wave_errors").inc()
                 RECORDER.record("wave_error", store=store_name,
@@ -510,5 +519,6 @@ class GeStoreService:
                     if not fut.done() and fut.set_running_or_notify_cancel():
                         fut.set_exception(e)
         if enforce_pool and self.pool is not None:
-            self.pool.enforce()
+            with StageTimer(trace, "serve", "enforce"):
+                self.pool.enforce()
         return len(pending)

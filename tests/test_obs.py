@@ -1,7 +1,8 @@
 """Observability layer: registry correctness under concurrency, histogram
 bounds, span nesting/propagation under a seeded thread stress, flight
-recorder ring semantics, kernel telemetry, and the front door's trace-id
-minting + per-tenant rejection accounting."""
+recorder ring semantics, and the front door's trace-id minting +
+per-tenant rejection accounting. The profiler leaves are tested in
+``test_trace_leaves.py``."""
 from __future__ import annotations
 
 import json
@@ -12,11 +13,9 @@ import numpy as np
 import pytest
 
 from repro.core.store import FieldSchema, VersionedStore
-from repro.launch.roofline import PEAKS, kernel_roofline
 from repro.obs import (FlightRecorder, Histogram, MetricsRegistry, RECORDER,
                        StageTimer, current_span, current_trace_id,
                        new_trace_id, span)
-from repro.obs.kerneltel import KernelTelemetry
 
 
 # -- metrics registry ---------------------------------------------------------
@@ -184,48 +183,6 @@ def test_recorder_dump_json_roundtrip(tmp_path):
     with open(path) as f:
         d = json.load(f)
     assert d["events"][0]["kind"] == "boom"
-
-
-# -- kernel telemetry ---------------------------------------------------------
-
-def test_kernel_telemetry_aggregates_and_derives_roofline():
-    tel = KernelTelemetry()
-    with tel.launch("k", nbytes=1e6, flops=2e6):
-        pass
-    with tel.launch("k", nbytes=1e6, flops=2e6):
-        pass
-    snap = tel.snapshot()["k"]
-    assert snap["calls"] == 2
-    assert snap["bytes"] == 2e6 and snap["flops"] == 4e6
-    # the CPU has no entry in the peak table: no roofline is reported
-    assert snap["device_kind"] not in PEAKS
-    assert snap["roofline_fraction"] is None and snap["dominant"] is None
-    # a listed chip divides by its own peaks: 1 MB at 819 GB/s is the
-    # memory-bound minimum, so a 2x slower wall is half the roofline
-    kind = "TPU v5 lite"
-    t_min = 1e6 / PEAKS[kind].hbm_bw
-    r = kernel_roofline(2e6, 1e6, 2 * t_min, device_kind=kind)
-    assert r["dominant"] == "memory"
-    assert r["roofline_fraction"] == pytest.approx(0.5)
-
-
-def test_kernel_telemetry_skips_failed_launches():
-    tel = KernelTelemetry()
-    with pytest.raises(ValueError):
-        with tel.launch("k", nbytes=1, flops=1):
-            raise ValueError("kernel blew up")
-    assert tel.snapshot() == {}
-
-
-def test_batched_select_launches_are_recorded():
-    from repro.obs.kerneltel import KERNELS
-    st = VersionedStore("T", [FieldSchema("a", 4, "int32")], capacity=64)
-    keys = [f"K{i}" for i in range(32)]
-    st.update(10, keys, {"a": np.arange(128, dtype=np.int32).reshape(32, 4)})
-    before = KERNELS.snapshot().get("batched_select", {}).get("calls", 0)
-    st.get_versions([10, 20, 30], fields=["a"])   # distinct ts: fused scan
-    after = KERNELS.snapshot()["batched_select"]["calls"]
-    assert after > before
 
 
 # -- front door integration ---------------------------------------------------
