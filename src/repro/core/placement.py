@@ -16,7 +16,7 @@ Execution modes, planned by :func:`plan_placement`:
     stacked operands laid out with ``NamedSharding(mesh, P("shard",
     None))`` so the scan partitions with zero communication. Value
     materialization then pays ONE fused cross-shard gather per field
-    (``take_cells``) instead of one per (shard, field).
+    (``start_cells``) instead of one per (shard, field).
   * ``stacked`` — fewer devices than shards but parallelism forced
     (``GESTORE_PARALLEL=1`` or an explicit plan): the same single stacked
     launch and fused gathers on one device. Still amortizes per-shard
@@ -52,9 +52,9 @@ import jax.numpy as jnp
 from repro.kernels import launch as klaunch
 from repro.kernels.batched_select import scan_bucket, stacked_boundary_select
 from repro.launch.mesh import make_shard_mesh
-from repro.obs.trace import StageTimer
 
-from .store import _SuperLog, _clamp_ts
+from .store import (_SuperLog, _clamp_ts, _gather_collect,
+                    _gather_start)
 
 #: env override: "0"/"off"/"serial" forces serial, "1"/"on"/"parallel"
 #: forces the stacked launch even with fewer devices than shards.
@@ -203,31 +203,26 @@ class PlacedSuperLog:
         """Per-shard cell offset of ``name`` in the fused value array."""
         return self._fused_field(name, superlogs)[1]
 
-    def take_cells(self, name: str, idx: np.ndarray, keep: np.ndarray,
-                   lens, superlogs, trace: dict | None = None
-                   ) -> list[np.ndarray]:
-        """One fused device gather for a whole wave: ``idx`` holds global
+    def start_cells(self, name: str, idx: np.ndarray, keep: np.ndarray,
+                    superlogs) -> tuple:
+        """Launch one fused device gather for a whole wave and start its
+        copy to the host, without waiting for either: ``idx`` holds global
         cell positions (already permuted into every query's final merged
-        row order, queries back to back with per-query ``lens``) and
-        ``keep`` masks rows whose value must be zeroed (no cell at the
-        query time / deleted rows) — the same semantics as
-        ``_SuperLog.gather_finalize``, minus the host-side mutation. The
-        device take is the ``gather.take`` leaf, its copy to the host
-        ``gather.copy``."""
-        with StageTimer(trace, "gather", "take"):
-            dev, _offs, total, width, dtype = self._fused_field(name,
-                                                                superlogs)
-            if dev is None or len(idx) == 0:
-                return [np.zeros((int(n), width), dtype) for n in lens]
-            out = jnp.where(
-                jnp.asarray(keep)[:, None],
-                jnp.take(dev, jnp.asarray(np.clip(idx, 0, total - 1)),
-                         axis=0),
-                jnp.zeros((), dev.dtype))
-        with StageTimer(trace, "gather", "copy"):
-            out = np.asarray(out)
-            cum = np.cumsum([0] + list(lens))
-            return [out[cum[i]: cum[i + 1]] for i in range(len(lens))]
+        row order, queries back to back) and ``keep`` masks rows whose
+        value must be zeroed (no cell at the query time / deleted rows) —
+        the semantics of ``_SuperLog.gather_dispatch``. Returns the handle
+        ``collect_cells`` takes."""
+        dev, _offs, total, width, dtype = self._fused_field(name, superlogs)
+        return _gather_start(
+            dev, np.where(keep, np.clip(idx, 0, total - 1), -1), dtype, width)
+
+    @staticmethod
+    def collect_cells(handle: tuple, lens) -> list[np.ndarray]:
+        """The host rows of a ``start_cells``, split per query (``lens``
+        rows each): read-only views of one host copy."""
+        out = _gather_collect(handle)
+        cum = np.cumsum([0] + list(lens))
+        return [out[cum[i]: cum[i + 1]] for i in range(len(lens))]
 
     def exists_matrices(self, bcums, superlogs) -> list[tuple]:
         """Per-shard ``(alive, ever)`` — ``_SuperLog.exists_matrix`` for

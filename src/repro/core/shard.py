@@ -538,8 +538,8 @@ class ShardedStore:
                     [self._shard_rows(s)[v.row_idx]
                      for s, v in enumerate(views)])
                 values = {
-                    name: np.concatenate([v.values[name]
-                                          for v in views])[order]
+                    name: store_mod._read_only(np.concatenate(
+                        [v.values[name] for v in views])[order])
                     for name in fields}
                 by_t[t] = VersionView(
                     ts=t, keys=[self.row_keys[r] for r in rows],
@@ -595,8 +595,9 @@ class ShardedStore:
         rows_q = np.split(rows_all, np.cumsum(lens_q)[:-1])
         values_q: list[dict] = [{} for _ in range(nq)]
         store_mod._check_cancel(cancel)
-        for name in fields:
-            with store_mod._StageTimer(trace, "gather", "take"):
+        handles = {}
+        with store_mod._StageTimer(trace, "gather", "take"):
+            for name in fields:
                 offs = placed.field_offsets(name, sls)
                 iparts, kparts = [], []
                 for s in range(ns):
@@ -605,10 +606,14 @@ class ShardedStore:
                     iparts.append(offs[s] + np.clip(
                         f.ptr[sel_cat[s]] + c - 1, 0, max(f.n_cells - 1, 0)))
                     kparts.append(c > 0)
-            for qi, v in enumerate(placed.take_cells(
+                handles[name] = placed.start_cells(
                     name, np.concatenate(iparts)[perm],
-                    np.concatenate(kparts)[perm], lens_q, sls, trace)):
-                values_q[qi][name] = v
+                    np.concatenate(kparts)[perm], sls)
+        with store_mod._StageTimer(trace, "gather", "copy"):
+            for name in fields:
+                for qi, v in enumerate(placed.collect_cells(handles[name],
+                                                            lens_q)):
+                    values_q[qi][name] = v
         with store_mod._StageTimer(trace, "materialize"):
             return [VersionView(ts=t,
                                 keys=[self.row_keys[r] for r in rows_q[qi]],
@@ -656,8 +661,8 @@ class ShardedStore:
                      for s, inc in enumerate(incs)])
                 kind = np.concatenate([inc.kind for inc in incs])[order]
                 values = {
-                    name: np.concatenate([inc.values[name]
-                                          for inc in incs])[order]
+                    name: store_mod._read_only(np.concatenate(
+                        [inc.values[name] for inc in incs])[order])
                     for name in out_fields}
                 by_pair[(t0, t1)] = Increment(
                     t0=t0, t1=t1, keys=[self.row_keys[r] for r in rows],
@@ -723,8 +728,9 @@ class ShardedStore:
             kind_q = np.split(kind_all, cuts)
             not_deleted = kind_all != KIND_DELETED
         values_q: list[dict] = [{} for _ in upairs]
-        for name in out_fields:
-            with store_mod._StageTimer(trace, "gather", "take"):
+        handles = {}
+        with store_mod._StageTimer(trace, "gather", "take"):
+            for name in out_fields:
                 offs = placed.field_offsets(name, sls)
                 iparts, kparts = [], []
                 for s in range(ns):
@@ -733,11 +739,14 @@ class ShardedStore:
                     iparts.append(offs[s] + np.clip(
                         f.ptr[sel_cat[s]] + c - 1, 0, max(f.n_cells - 1, 0)))
                     kparts.append(c > 0)
-            for qi, v in enumerate(placed.take_cells(
+                handles[name] = placed.start_cells(
                     name, np.concatenate(iparts)[perm],
-                    np.concatenate(kparts)[perm] & not_deleted,
-                    lens_q, sls, trace)):
-                values_q[qi][name] = v
+                    np.concatenate(kparts)[perm] & not_deleted, sls)
+        with store_mod._StageTimer(trace, "gather", "copy"):
+            for name in out_fields:
+                for qi, v in enumerate(placed.collect_cells(handles[name],
+                                                            lens_q)):
+                    values_q[qi][name] = v
         with store_mod._StageTimer(trace, "materialize"):
             return [Increment(t0=t0, t1=t1,
                               keys=[self.row_keys[r] for r in rows_q[qi]],

@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
+from repro.obs.metrics import REGISTRY
 from repro.obs.trace import StageTimer
 
 Timestamp = int
@@ -386,6 +387,92 @@ class _CellLog:
         return (cum[ptr[1:]] - cum[ptr[:-1]]).astype(np.int32)
 
 
+#: rows the word gather moves per loop step: on the TPU a narrow row of
+#: an intermediate pads to 128 lanes, so this bounds the step's temporary
+#: buffers to ~8 MB whatever the block's size (rows up to 512 bytes); the
+#: chip's compiler also takes half as long over a step of this size as
+#: over one four times larger
+_WORD_STEP_ROWS = 1 << 14
+
+
+def _row_words(dtype: np.dtype, width: int) -> int:
+    """32-bit words a gathered row of ``width`` x ``dtype`` takes: its
+    bytes rounded up to whole words (fields are at most 32 bits wide)."""
+    return -(-width * dtype.itemsize // 4)
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "steps"))
+def _take_words(vals, idx, *, dtype, steps):
+    """Rows ``idx`` of ``vals`` as ``dtype`` (a negative index: a zero row,
+    no cell at the query time or a deleted row), as ONE flat vector of
+    32-bit words, each row padded to whole words: the host receives it in
+    linear memory and views it back as rows without a copy. A bool field
+    travels as its 0/1 bytes. The block is gathered in ``steps`` row
+    ranges written into the flat result, so the bitcast's temporary
+    buffers stay one step's size; the row axis is padded to whole steps
+    of a multiple of 8 rows (fewer than 8 padding rows a step)."""
+    wire = jnp.uint8 if dtype == np.bool_ else dtype
+    per = 4 // dtype.itemsize            # values per word
+    wpr = _row_words(dtype, vals.shape[1])
+    rows = -(-idx.shape[0] // steps)
+    rows = -(-rows // 8) * 8
+    idx = jnp.pad(idx, (0, rows * steps - idx.shape[0]), constant_values=-1)
+
+    def step(i, flat):
+        ix = jax.lax.dynamic_slice(idx, (i * rows,), (rows,))
+        out = jnp.take(vals, jnp.maximum(ix, 0), axis=0).astype(wire)
+        out = jnp.where((ix >= 0)[:, None], out, jnp.zeros((), wire))
+        if wpr * per != out.shape[1]:
+            out = jnp.pad(out, ((0, 0), (0, wpr * per - out.shape[1])))
+        if per > 1:
+            out = out.reshape(rows, wpr, per)
+        words = jax.lax.bitcast_convert_type(out, jnp.uint32).reshape(-1)
+        return jax.lax.dynamic_update_slice(flat, words, (i * rows * wpr,))
+
+    return jax.lax.fori_loop(0, steps, step,
+                             jnp.zeros(rows * steps * wpr, jnp.uint32))
+
+
+def _read_only(a: np.ndarray) -> np.ndarray:
+    a.setflags(write=False)
+    return a
+
+
+def _gather_start(vals, idx: np.ndarray, dtype: np.dtype, width: int):
+    """Launch one field's gather of rows ``idx`` of the device array
+    ``vals`` (negative index: a zero row; ``vals`` None: every row zero)
+    and start its copy to the host without waiting for it. Returns the
+    handle ``_gather_collect`` takes.
+
+    The copy moves flat 32-bit words (``_take_words``): a gathered (R, W)
+    int8 block in its tiled device layout crosses to the host far slower
+    than the same bytes as linear words. Counters: ``gather.host_bytes``,
+    ``gather.word_copies``."""
+    if vals is None or not len(idx):
+        return None, len(idx), width, dtype
+    dev = _take_words(vals, jnp.asarray(idx, np.int32), dtype=dtype,
+                      steps=-(-len(idx) // _WORD_STEP_ROWS))
+    REGISTRY.counter("gather.word_copies").inc()
+    REGISTRY.counter("gather.host_bytes").inc(dev.nbytes)
+    dev.copy_to_host_async()
+    return dev, len(idx), width, dtype
+
+
+def _gather_collect(handle) -> np.ndarray:
+    """The (R, W) host rows of a ``_gather_start``, read-only: a view of
+    the copied words where each row filled whole words, else a copy with
+    each row's padding stripped."""
+    dev, n, width, dtype = handle
+    if dev is None:
+        return _read_only(np.zeros((n, width), dtype))
+    wpr = _row_words(dtype, width)
+    per = 4 // dtype.itemsize
+    rows = np.asarray(dev)[: n * wpr].view(dtype).reshape(n, wpr * per)
+    if wpr * per == width:
+        return rows
+    return _read_only(np.ascontiguousarray(rows[:, :width]))
+
+
 @dataclasses.dataclass
 class _SuperLogField:
     """One log's slice of the fused superlog.
@@ -424,20 +511,22 @@ class _SuperLogField:
             self._vals_dev = self._put(self.vals_host)
         return self._vals_dev
 
-    def take_cells(self, idx):
+    def take_cells(self, idx: np.ndarray):
         """ONE fused device gather of cell values at field-local cell
-        indices. Delta-packed fields decode on device first (segmented
-        scan over the narrowed deltas), so the wide decoded array exists
-        only transiently inside the launch — HBM holds the packed copy."""
-        idx = jnp.asarray(idx)
+        indices (negative: a zero row), its copy to the host started:
+        a ``_gather_start`` handle. Delta-packed fields decode on device
+        first (segmented scan over the narrowed deltas), so the wide
+        decoded array exists only transiently — HBM holds the packed
+        copy; the gather truncates the int32 scan to the stored dtype,
+        as the host depth-loop does."""
         if self.packed_host is None:
-            return jnp.take(self.vals_dev(), idx, axis=0)
-        if self._packed_dev is None:
-            self._packed_dev = self._put(self.packed_host)
-            self._heads_dev = self._put(self.heads_host)
-        decoded = kops.chain_decode(self._packed_dev, self._heads_dev)
-        # int32 scan truncated to the stored dtype == the host depth-loop
-        return jnp.take(decoded.astype(self.dtype), idx, axis=0)
+            vals = self.vals_dev()
+        else:
+            if self._packed_dev is None:
+                self._packed_dev = self._put(self.packed_host)
+                self._heads_dev = self._put(self.heads_host)
+            vals = kops.chain_decode(self._packed_dev, self._heads_dev)
+        return _gather_start(vals, idx, self.dtype, self.width)
 
     def dev_nbytes(self) -> int:
         n = 0
@@ -595,31 +684,33 @@ class _SuperLog:
         return (v > 0) & ever, ever
 
     def gather_dispatch(self, name: str, cnts: "Sequence[np.ndarray]",
-                        sels: Sequence[np.ndarray]) -> tuple:
-        """Launch the fused per-field gather WITHOUT forcing a host sync:
-        returns an opaque handle for ``gather_finalize``. The sharded
-        facade dispatches every shard's gathers (each on its own device
-        under placement) before collecting any, so they overlap."""
+                        sels: Sequence[np.ndarray],
+                        zero: Sequence[np.ndarray] | None = None) -> tuple:
+        """Launch the fused per-field gather and start its copy to the
+        host, WITHOUT waiting for either: returns an opaque handle for
+        ``gather_finalize``. Rows with no cell at the query time, and the
+        rows ``zero[q]`` marks, are zeroed on the device (the semantics
+        of _CellLog.select_at). Dispatching every field before
+        collecting any lets the copies overlap each other and the
+        remaining gathers."""
         f = self.fields[name]
         lens = [len(s) for s in sels]
         if f.vals_host is None or sum(lens) == 0:
-            return (None, lens, None)
+            return _gather_start(None, np.concatenate(sels), f.dtype,
+                                 f.width), lens
         cat_cnt = np.concatenate([c[s] for c, s in zip(cnts, sels)])
         cat_rows = np.concatenate(sels)
         idx = np.clip(f.ptr[cat_rows] + cat_cnt - 1, 0, f.n_cells - 1)
-        dev = f.take_cells(idx)  # decodes delta-packed fields on device
-        return (dev, lens, cat_cnt)
+        keep = cat_cnt > 0
+        if zero is not None:
+            keep &= ~np.concatenate(zero)
+        return f.take_cells(np.where(keep, idx, -1)), lens
 
-    def gather_finalize(self, name: str, handle: tuple) -> list[np.ndarray]:
-        """Collect a ``gather_dispatch`` result to host, split per query.
-        Rows with no cell at the query time come back zeroed (same
-        semantics as _CellLog.select_at)."""
-        dev, lens, cat_cnt = handle
-        f = self.fields[name]
-        if dev is None:
-            return [np.zeros((l, f.width), f.dtype) for l in lens]
-        out = np.array(dev)
-        out[cat_cnt <= 0] = 0
+    def gather_finalize(self, handle: tuple) -> list[np.ndarray]:
+        """Collect a ``gather_dispatch`` result on the host, split per
+        query: read-only views of one host copy."""
+        handle, lens = handle
+        out = _gather_collect(handle)
         offs = np.cumsum([0] + lens)
         return [out[offs[i]: offs[i + 1]] for i in range(len(lens))]
 
@@ -631,20 +722,17 @@ class _SuperLog:
         """Per-query row selections fused into ONE device gather per field:
         ``cnts(name)[q]`` the (N,) per-row counts and ``sels[q]`` the
         selected rows of query q; rows where ``zero[q]`` is set come back
-        zeroed. Each field is two leaves of the gather stage:
-        ``gather.take`` (index math, device take or decode) and
-        ``gather.copy`` (the copy to the host, which waits for the take,
-        and the zeroing)."""
-        out = {}
-        for name in names:
-            with StageTimer(trace, "gather", "take"):
-                handle = self.gather_dispatch(name, cnts(name), sels)
-            with StageTimer(trace, "gather", "copy"):
-                parts = self.gather_finalize(name, handle)
-                for v, z in zip(parts, zero or ()):
-                    v[z] = 0
-            out[name] = parts
-        return out
+        zeroed. Two leaves of the gather stage: ``gather.take`` launches
+        every field's gather (index math, device take or decode) and
+        starts its copy; ``gather.copy`` then collects the fields in
+        order, waiting for each copy to land."""
+        with StageTimer(trace, "gather", "take"):
+            handles = {name: self.gather_dispatch(name, cnts(name), sels,
+                                                  zero)
+                       for name in names}
+        with StageTimer(trace, "gather", "copy"):
+            return {name: self.gather_finalize(handles[name])
+                    for name in names}
 
 
 class _FieldColumn:
@@ -1278,7 +1366,9 @@ class VersionedStore:
             ``repro.obs.trace``). Additive across calls.
 
         Returns:
-          list[VersionView] aligned with ``ts_list``.
+          list[VersionView] aligned with ``ts_list``. Every array in a
+          view's ``values`` is read-only (often a view of one host copy
+          shared by the wave's queries): copy it to write into it.
 
         Raises:
           KeyError: an unknown field name.
@@ -1352,7 +1442,9 @@ class VersionedStore:
             with _StageTimer(trace, "gather", "take"):
                 handle = log.select_dispatch(self.n_rows, t)
             with _StageTimer(trace, "gather", "copy"):
-                values[name] = log.select_collect(self.n_rows, handle)[0][sel]
+                values[name] = _read_only(log.select_collect(
+                    self.n_rows, handle)[0][sel].astype(log.dtype,
+                                                        copy=False))
         with _StageTimer(trace, "materialize"):
             return VersionView(ts=t, keys=[self.row_keys[r] for r in sel],
                                row_idx=sel.astype(np.int32), values=values)
@@ -1383,7 +1475,7 @@ class VersionedStore:
 
         Returns:
           list[Increment] aligned with ``pairs`` (values at t1, zeroed
-          for deleted rows).
+          for deleted rows; read-only arrays, as in ``get_versions``).
 
         Raises:
           KeyError: an unknown field name.
@@ -1455,9 +1547,10 @@ class VersionedStore:
             with _StageTimer(trace, "gather", "take"):
                 handle = log.select_dispatch(self.n_rows, t1)
             with _StageTimer(trace, "gather", "copy"):
-                v = log.select_collect(self.n_rows, handle)[0][sel]
+                v = log.select_collect(self.n_rows, handle)[0][sel].astype(
+                    log.dtype, copy=False)
                 v[kind == KIND_DELETED] = 0
-            values[name] = v
+            values[name] = _read_only(v)
         with _StageTimer(trace, "materialize"):
             return Increment(t0=t0, t1=t1,
                              keys=[self.row_keys[r] for r in sel],

@@ -153,3 +153,30 @@ def test_keep_mask_compiles(one_chip):
     _assert_kernel(compact_rewrite._keep_mask.lower(
         _arg((N_ROWS,), jnp.int32, one_chip), cutoff=20, interpret=False,
         tile=launch.DEFAULT_TILES["compact_rewrite"]))
+
+
+#: rows of one all-field read wave of two Swiss-Prot versions
+WAVE_ROWS = 1_148_550
+
+
+@pytest.mark.parametrize("w,dtype", UNIPROT_WIDTHS,
+                         ids=["w512_int8", "w256_int8", "w1_int32"])
+def test_take_words_compiles_without_a_block_sized_temporary(one_chip, w,
+                                                             dtype):
+    """The store's word gather (``core/store._take_words``) at a read
+    wave's size: its bitcast to 32-bit words runs a loop step at a time,
+    so the chip's compiler keeps no temporary near the block's size (a
+    minor dimension of 4 would pad to 128 lanes; a whole-block bitcast
+    holds two more blocks)."""
+    import numpy as np
+    from repro.core import store
+    dt = np.dtype(dtype)
+    steps = -(-WAVE_ROWS // store._WORD_STEP_ROWS)
+    compiled = store._take_words.lower(
+        _arg((2 * N_ROWS, w), dtype, one_chip),
+        _arg((WAVE_ROWS,), jnp.int32, one_chip), dtype=dt,
+        steps=steps).compile()
+    mem = compiled.memory_analysis()
+    block = WAVE_ROWS * w * dt.itemsize
+    assert mem.output_size_in_bytes < block * 1.01
+    assert mem.temp_size_in_bytes < max(block // 16, 16 << 20)

@@ -240,6 +240,63 @@ def test_no_leaf_comes_from_parse_or_shard_worker_threads(profiled):
     assert len(by_line) == 1
 
 
+@pytest.mark.parametrize("kind", ["unsharded", "stacked"])
+def test_multi_field_read_starts_every_copy_before_collecting(tmp_path,
+                                                              kind):
+    """A version read of three fields of a UniProt store's shapes, on one
+    store and through the sharded facade's stacked path: the
+    ``gather.take`` leaves launch every field's gather and start its
+    copy, then one ``gather.copy`` leaf collects them all; the leaves
+    fill the gather stage and do not overlap on the thread that drives
+    them."""
+    import jax
+    from jax.profiler import ProfileData
+
+    rng = np.random.default_rng(5)
+    schema = [FieldSchema("seq", 512, "int8"), FieldSchema("len", 1, "int32"),
+              FieldSchema("ann", 256, "int8")]
+    if kind == "unsharded":
+        st = VersionedStore("W", schema, capacity=64)
+    else:
+        st = ShardedStore("W", schema, n_shards=2, capacity=64)
+        st.placement = plan_placement(2, force="parallel")
+    keys = [f"K{i:02d}" for i in range(32)]
+    for ts in (10, 20):
+        st.update(ts, keys, {
+            "seq": rng.integers(-128, 128, (32, 512)).astype(np.int8),
+            "len": rng.integers(0, 999, (32, 1)).astype(np.int32),
+            "ann": rng.integers(-128, 128, (32, 256)).astype(np.int8)})
+    st.get_versions([10, 20])  # compile outside the trace
+    before = REGISTRY.counter("gather.word_copies").value
+    trace: dict[str, float] = {}
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        views = st.get_versions([10, 20], trace=trace)
+    finally:
+        jax.profiler.stop_trace()
+    assert REGISTRY.counter("gather.word_copies").value - before == 3
+    assert all(set(v.values) == {"seq", "len", "ann"} for v in views)
+    assert trace["gather.take"] > 0 and trace["gather.copy"] > 0
+    assert trace["gather"] == pytest.approx(_sum_of_leaves(trace, "gather"),
+                                            rel=1e-12)
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    lines = [[(e.start_ns, e.start_ns + e.duration_ns, e.name)
+              for e in ln.events if e.name.startswith("gestore.gather.")]
+             for p in ProfileData.from_file(path).planes
+             if p.name.startswith("/host:") for ln in p.lines]
+    lines = [sorted(evs) for evs in lines if evs]
+    assert len(lines) == 1
+    evs = lines[0]
+    # the row selection and the launches are take leaves; one copy leaf
+    # after the last of them collects every field
+    assert [n for *_se, n in evs][-1] == "gestore.gather.copy"
+    assert {n for *_se, n in evs[:-1]} == {"gestore.gather.take"}
+    for (_s0, e0, _n0), (s1, _e1, _n1) in zip(evs, evs[1:]):
+        assert s1 >= e0
+
+
 # -- storage.bytes_written -----------------------------------------------------
 
 def _regular_bytes(root):
